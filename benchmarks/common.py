@@ -20,16 +20,26 @@ def emit(name: str, us_per_call: float, derived: str):
     print(f"{name},{us_per_call:.1f},{derived}", flush=True)
 
 
-def subproc_env(sentinel: str | None = None) -> dict:
+def subproc_env() -> dict:
     """Environment for a benchmark subprocess: PYTHONPATH includes
-    `src` (the drivers import `repro.*` from the source tree), and
-    `sentinel`, when given, marks the child as already re-executed so
-    the device-count re-exec guards cannot loop."""
-    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+    `src` (the drivers import `repro.*` from the source tree)."""
+    return dict(os.environ, PYTHONPATH=os.pathsep.join(
         p for p in ("src", os.environ.get("PYTHONPATH")) if p))
-    if sentinel:
-        env[sentinel] = "1"
-    return env
+
+
+def require_devices(n: int, name: str) -> None:
+    """Fail unless this process sees at least `n` JAX devices. A driver
+    runs in the process that calls it and never re-executes itself: on
+    a CPU host, the caller forces devices with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before JAX
+    starts."""
+    import jax
+    have = len(jax.devices())
+    if have < n:
+        raise RuntimeError(
+            f"{name} needs {n} devices and this process has {have}; on "
+            f"a CPU host set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n}")
 
 
 #: `benchmarks.run --regress` fails a driver whose re-measured

@@ -42,9 +42,11 @@ N_TREES, DEPTH, N_CLASSES, N_FEATURES = 24, 4, 4, 16
 #: dominated — per-process dispatch overhead varies ~2x on small CI
 #: boxes and would otherwise flap the regression gate
 BATCH = 4096
-#: (block_b, block_t) sweep — grid shapes from (1, 1) to (32, 12)
-TILES = ((4096, None), (512, None), (512, 8), (128, 8), (128, 2))
-SMOKE_TILES = ((128, None), (128, 4))
+#: (block_b, block_t) sweep. 24 trees of depth 4 span 96 gather
+#: columns, less than one 128-lane tile, so every tile holds the whole
+#: ensemble (`resolve_block_t`) and the sweep is over batch tiles
+TILES = ((4096, None), (512, None), (128, None))
+SMOKE_TILES = ((128, None), (64, None))
 
 
 def _best_of(fn, repeat: int = 7):
@@ -82,9 +84,9 @@ def _ref_fn(f):
 
 
 def _tiled_fn(packed, n_trees, block_b, block_t):
-    gather, thr, leaf, _t, d, _kind = packed
+    *operands, _t, d, _kind = packed
     return jax.jit(lambda x: forest_predict_pallas(
-        x, gather, thr, leaf, n_trees, d, block_b=block_b,
+        x, *operands, n_trees, d, block_b=block_b,
         block_t=block_t, interpret=jax.default_backend() != "tpu"))
 
 
@@ -92,7 +94,7 @@ def _time_tiles(f, xq, tiles) -> list:
     """One row per tile shape; parity vs the reference is asserted
     under the same clock that times the kernel."""
     packed = pack_forest(f)
-    t = packed[3]
+    t, d = packed[5], packed[6]
     b = xq.shape[0]
     p_ref = np.asarray(_ref_fn(f)(xq))
     rows = []
@@ -107,7 +109,7 @@ def _time_tiles(f, xq, tiles) -> list:
             lambda fn=fn, xp=xp: np.asarray(
                 jax.block_until_ready(fn(xp))))
         np.testing.assert_allclose(summed[:b] / t, p_ref, atol=1e-5)
-        bt = resolve_block_t(t, block_t)
+        bt = resolve_block_t(t, d, block_t)
         row = {"block_b": bb, "block_t": bt,
                "grid": [xp.shape[0] // bb, t // bt],
                "us_per_call": us, "rows_per_s": b / (us * 1e-6)}

@@ -37,22 +37,12 @@ controller-on row against the committed baseline.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
 import time
 
-#: 4 shards want 4 devices; set before JAX initializes (see
-#: `benchmarks/serve_sharded` for the re-exec rationale).
-_FLAG = "--xla_force_host_platform_device_count=4"
-if "xla_force_host_platform_device_count" not in \
-        os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = \
-        (os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()
-
 import numpy as np
 
-from benchmarks.common import emit, regress_gate, subproc_env
+from benchmarks.common import emit, regress_gate, require_devices
 from benchmarks.serve_emergency import (
     BLADES_PER_CHASSIS, BUDGET_2X, CORES_PER_SERVER, N_CHASSIS,
     _sweep_power, _train, _warm_state)
@@ -298,10 +288,7 @@ def overhead(smoke: bool = False) -> dict:
 
 
 def run(out_path: str = OUT_PATH, smoke: bool = False) -> dict:
-    import jax
-    if len(jax.devices()) < N_SHARDS \
-            and "REPRO_SERVE_ADAPTIVE_SUBPROC" not in os.environ:
-        return _reexec(out_path, smoke)
+    require_devices(N_SHARDS, "serve_adaptive")
     out = {"sweep": sweep(smoke), "overhead": overhead(smoke)}
     if not smoke:
         with open(out_path, "w") as f:
@@ -309,36 +296,11 @@ def run(out_path: str = OUT_PATH, smoke: bool = False) -> dict:
     return out
 
 
-def _reexec(out_path: str, smoke: bool) -> dict:
-    """Re-run in a fresh interpreter where the forced device count can
-    still take effect (same trap as `benchmarks/serve_sharded`)."""
-    cmd = [sys.executable, "-m", "benchmarks.serve_adaptive"]
-    if smoke:
-        cmd.append("--smoke")
-    subprocess.run(cmd,
-                   env=subproc_env("REPRO_SERVE_ADAPTIVE_SUBPROC"),
-                   check=True)
-    if smoke:
-        return {}
-    with open(out_path) as f:
-        return json.load(f)
-
-
 def regress(baseline: dict) -> list:
     """Benchmark-regression gate (``benchmarks.run --regress``):
     re-measure the 4-shard controller-on row quickly and fail on a
     >30% arrivals/s drop vs the committed BENCH_serve_adaptive.json."""
-    import jax
-    if len(jax.devices()) < N_SHARDS:
-        if "REPRO_SERVE_ADAPTIVE_SUBPROC" in os.environ:
-            return [f"serve_adaptive: {len(jax.devices())} devices "
-                    f"in subprocess, need {N_SHARDS}"]
-        rc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.serve_adaptive",
-             "--regress"],
-            env=subproc_env("REPRO_SERVE_ADAPTIVE_SUBPROC")).returncode
-        return [] if rc == 0 else \
-            [f"serve_adaptive: regress subprocess exited {rc}"]
+    require_devices(N_SHARDS, "serve_adaptive")
     want = next(r for r in baseline["overhead"]["configs"]
                 if r["adaptive"])
     hist, arrivals, labels, svc = _train(n_trees=48)

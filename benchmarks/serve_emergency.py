@@ -23,22 +23,12 @@ committed baseline.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
 import time
 
-#: 4 shards want 4 devices; set before JAX initializes (see
-#: `benchmarks/serve_sharded` for the re-exec rationale).
-_FLAG = "--xla_force_host_platform_device_count=4"
-if "xla_force_host_platform_device_count" not in \
-        os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = \
-        (os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()
-
 import numpy as np
 
-from benchmarks.common import emit, regress_gate, subproc_env
+from benchmarks.common import emit, regress_gate, require_devices
 from repro.core import features as F
 from repro.core.placement import ClusterState, SchedulerPolicy
 from repro.core.predictor import train_service
@@ -158,9 +148,7 @@ def _push_stream(pipe, arrivals, batch_size, emergency: bool,
 
 def run(out_path: str = OUT_PATH, smoke: bool = False) -> dict:
     import jax
-    if len(jax.devices()) < max(SHARD_COUNTS) \
-            and "REPRO_SERVE_EMERGENCY_SUBPROC" not in os.environ:
-        return _reexec(out_path, smoke)
+    require_devices(max(SHARD_COUNTS), "serve_emergency")
     hist, arrivals, labels, svc = _train(n_trees=12 if smoke else 48)
     if smoke:
         arrivals = F.Population(vms=arrivals.vms[:256])
@@ -238,36 +226,11 @@ def run(out_path: str = OUT_PATH, smoke: bool = False) -> dict:
     return out
 
 
-def _reexec(out_path: str, smoke: bool) -> dict:
-    """Re-run in a fresh interpreter where the forced device count can
-    still take effect (same trap as `benchmarks/serve_sharded`)."""
-    cmd = [sys.executable, "-m", "benchmarks.serve_emergency"]
-    if smoke:
-        cmd.append("--smoke")
-    subprocess.run(cmd,
-                   env=subproc_env("REPRO_SERVE_EMERGENCY_SUBPROC"),
-                   check=True)
-    if smoke:
-        return {}
-    with open(out_path) as f:
-        return json.load(f)
-
-
 def regress(baseline: dict) -> list:
     """Benchmark-regression gate (``benchmarks.run --regress``):
     re-measure the 4-shard emergency-on row quickly and fail on a >30%
     arrivals/s drop vs the committed BENCH_serve_emergency.json."""
-    import jax
-    if len(jax.devices()) < max(SHARD_COUNTS):
-        if "REPRO_SERVE_EMERGENCY_SUBPROC" in os.environ:
-            return [f"serve_emergency: {len(jax.devices())} devices in "
-                    f"subprocess, need {max(SHARD_COUNTS)}"]
-        rc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.serve_emergency",
-             "--regress"],
-            env=subproc_env("REPRO_SERVE_EMERGENCY_SUBPROC")).returncode
-        return [] if rc == 0 else \
-            [f"serve_emergency: regress subprocess exited {rc}"]
+    require_devices(max(SHARD_COUNTS), "serve_emergency")
     want = next(r for r in baseline["configs"]
                 if r["n_shards"] == 4 and r["emergency"])
     hist, arrivals, labels, svc = _train(n_trees=48)

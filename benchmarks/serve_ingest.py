@@ -18,22 +18,12 @@ ingest data plane in events/s. Writes BENCH_serve_ingest.json;
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
 import time
 
-#: 4 shards want 4 devices; set before JAX initializes (see
-#: `benchmarks/serve_sharded` for the re-exec rationale).
-_FLAG = "--xla_force_host_platform_device_count=4"
-if "xla_force_host_platform_device_count" not in \
-        os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = \
-        (os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()
-
 import numpy as np
 
-from benchmarks.common import emit, regress_gate, subproc_env
+from benchmarks.common import emit, regress_gate, require_devices
 from repro.core import features as F
 from repro.core.placement import SchedulerPolicy
 from repro.core.predictor import train_service
@@ -115,25 +105,9 @@ class _MergeOnly:
         return None
 
 
-def _reexec(out_path: str, smoke: bool) -> dict:
-    """Re-run in a fresh interpreter where the forced device count can
-    still take effect (same trap as `benchmarks/serve_sharded`)."""
-    cmd = [sys.executable, "-m", "benchmarks.serve_ingest"]
-    if smoke:
-        cmd.append("--smoke")
-    subprocess.run(cmd, env=subproc_env("REPRO_SERVE_INGEST_SUBPROC"),
-                   check=True)
-    if smoke:
-        return {}
-    with open(out_path) as f:
-        return json.load(f)
-
-
 def run(out_path: str = OUT_PATH, smoke: bool = False) -> dict:
     import jax
-    if len(jax.devices()) < N_SHARDS \
-            and "REPRO_SERVE_INGEST_SUBPROC" not in os.environ:
-        return _reexec(out_path, smoke)
+    require_devices(N_SHARDS, "serve_ingest")
     host_counts = (1, 4) if smoke else HOST_COUNTS
     hist, arrivals, labels, svc = _train(n_trees=12 if smoke else 48)
     if smoke:
@@ -188,17 +162,7 @@ def regress(baseline: dict) -> list:
     """Benchmark-regression gate (``benchmarks.run --regress``):
     re-measure the 4-host row quickly and fail on a >30% arrivals/s
     drop vs the committed BENCH_serve_ingest.json."""
-    import jax
-    if len(jax.devices()) < N_SHARDS:
-        if "REPRO_SERVE_INGEST_SUBPROC" in os.environ:
-            return [f"serve_ingest: {len(jax.devices())} devices in "
-                    f"subprocess, need {N_SHARDS}"]
-        rc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.serve_ingest",
-             "--regress"],
-            env=subproc_env("REPRO_SERVE_INGEST_SUBPROC")).returncode
-        return [] if rc == 0 else \
-            [f"serve_ingest: regress subprocess exited {rc}"]
+    require_devices(N_SHARDS, "serve_ingest")
     want = next(r for r in baseline["hosts"] if r["n_hosts"] == 4)
     hist, arrivals, labels, svc = _train(n_trees=48)
     arrivals = F.Population(vms=arrivals.vms[:768])

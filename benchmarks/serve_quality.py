@@ -19,22 +19,12 @@ committed baseline.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
 import time
 
-#: 4 shards want 4 devices; set before JAX initializes (see
-#: `benchmarks/serve_sharded` for the re-exec rationale).
-_FLAG = "--xla_force_host_platform_device_count=4"
-if "xla_force_host_platform_device_count" not in \
-        os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = \
-        (os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()
-
 import numpy as np
 
-from benchmarks.common import emit, regress_gate, subproc_env
+from benchmarks.common import emit, regress_gate, require_devices
 from benchmarks.serve_emergency import (
     BLADES_PER_CHASSIS, BUDGET_2X, CORES_PER_SERVER, _push_stream,
     _sweep_power, _train, _warm_state)
@@ -80,9 +70,7 @@ def _make_pipe(svc, hist, labels, state, n_shards, batch_size,
 
 def run(out_path: str = OUT_PATH, smoke: bool = False) -> dict:
     import jax
-    if len(jax.devices()) < max(SHARD_COUNTS) \
-            and "REPRO_SERVE_QUALITY_SUBPROC" not in os.environ:
-        return _reexec(out_path, smoke)
+    require_devices(max(SHARD_COUNTS), "serve_quality")
     hist, arrivals, labels, svc = _train(n_trees=12 if smoke else 48)
     if smoke:
         arrivals = F.Population(vms=arrivals.vms[:256])
@@ -149,36 +137,11 @@ def run(out_path: str = OUT_PATH, smoke: bool = False) -> dict:
     return out
 
 
-def _reexec(out_path: str, smoke: bool) -> dict:
-    """Re-run in a fresh interpreter where the forced device count can
-    still take effect (same trap as `benchmarks/serve_sharded`)."""
-    cmd = [sys.executable, "-m", "benchmarks.serve_quality"]
-    if smoke:
-        cmd.append("--smoke")
-    subprocess.run(cmd,
-                   env=subproc_env("REPRO_SERVE_QUALITY_SUBPROC"),
-                   check=True)
-    if smoke:
-        return {}
-    with open(out_path) as f:
-        return json.load(f)
-
-
 def regress(baseline: dict) -> list:
     """Benchmark-regression gate (``benchmarks.run --regress``):
     re-measure the 4-shard full-bundle row quickly and fail on a >30%
     arrivals/s drop vs the committed BENCH_serve_quality.json."""
-    import jax
-    if len(jax.devices()) < max(SHARD_COUNTS):
-        if "REPRO_SERVE_QUALITY_SUBPROC" in os.environ:
-            return [f"serve_quality: {len(jax.devices())} devices in "
-                    f"subprocess, need {max(SHARD_COUNTS)}"]
-        rc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.serve_quality",
-             "--regress"],
-            env=subproc_env("REPRO_SERVE_QUALITY_SUBPROC")).returncode
-        return [] if rc == 0 else \
-            [f"serve_quality: regress subprocess exited {rc}"]
+    require_devices(max(SHARD_COUNTS), "serve_quality")
     want = next(r for r in baseline["configs"]
                 if r["n_shards"] == 4 and r["full"])
     hist, arrivals, labels, svc = _train(n_trees=48)

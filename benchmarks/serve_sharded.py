@@ -3,11 +3,12 @@
 Runs the same arrival stream as `benchmarks/serve_online` through
 `ShardedServePipeline` on a 64-chassis cluster (the fig-7 cluster
 padded from 60 to 64 chassis so every shard count divides it), with
-the shards mapped onto forced host-platform CPU devices
-(``XLA_FLAGS=--xla_force_host_platform_device_count=8`` — set below
-before JAX initializes). Each shard scans only B/N arrivals against
-S/N servers, so the protocol wins twice: shorter scans per shard and
-one scan per device in parallel under `shard_map`.
+one shard per device: the process must see at least 8 devices (on a
+CPU host, ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+before JAX starts; `--smoke` needs 4). Each shard scans only B/N
+arrivals against S/N servers, so the protocol wins twice: shorter
+scans per shard and one scan per device in parallel under
+`shard_map`.
 
 Both placement modes are measured (see `benchmarks/serve_online`):
 `rank_rule` (full two-rule rank aggregation) and `algorithm1` (the
@@ -18,23 +19,12 @@ serves one small batch per shard count (CI).
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
 import time
 
-#: Must be set before jax initializes; when it is already too late
-#: (another benchmark driver initialized the single-device backend
-#: first), `run` re-executes itself in a subprocess — see `_reexec`.
-_FLAG = "--xla_force_host_platform_device_count=8"
-if "xla_force_host_platform_device_count" not in \
-        os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = \
-        (os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()
-
 import numpy as np
 
-from benchmarks.common import emit, subproc_env
+from benchmarks.common import emit, require_devices
 from repro.core import features as F
 from repro.core.placement import SchedulerPolicy
 from repro.core.predictor import train_service
@@ -77,30 +67,10 @@ def _make_pipe(svc, hist, labels, n_shards, policy, batch_size):
                                   n_shards=n_shards))
 
 
-def _reexec(out_path: str, smoke: bool) -> dict:
-    """Run the benchmark in a fresh interpreter where the forced
-    device count can still take effect (XLA_FLAGS is read exactly once
-    at backend init, so a parent that already initialized a
-    single-device JAX — e.g. `benchmarks.run` after the serve driver —
-    would silently measure the vmap fallback and overwrite the
-    artifact with no-scaling rows)."""
-    cmd = [sys.executable, "-m", "benchmarks.serve_sharded"]
-    if smoke:
-        cmd.append("--smoke")
-    subprocess.run(cmd, env=subproc_env("REPRO_SERVE_SHARDED_SUBPROC"),
-                   check=True)
-    if smoke:
-        return {}
-    with open(out_path) as f:
-        return json.load(f)
-
-
 def run(out_path: str = OUT_PATH, smoke: bool = False) -> dict:
     import jax
     shard_counts = (1, 4) if smoke else SHARD_COUNTS
-    if len(jax.devices()) < max(shard_counts) \
-            and "REPRO_SERVE_SHARDED_SUBPROC" not in os.environ:
-        return _reexec(out_path, smoke)
+    require_devices(max(shard_counts), "serve_sharded")
     hist, arrivals, labels, svc = _train(n_trees=12 if smoke else 48)
     if smoke:
         arrivals = F.Population(vms=arrivals.vms[:128])
@@ -161,21 +131,9 @@ def regress(baseline: dict) -> list:
     """Benchmark-regression gate (``benchmarks.run --regress``):
     re-measure the rank_rule 4-shard row (the headline speedup config,
     same batch size and forest, fewer arrivals) and fail on a >30%
-    arrivals/s drop vs BENCH_serve_sharded.json. Re-execs itself when
-    the parent already initialized a small-device JAX (same trap as
-    `run`)."""
+    arrivals/s drop vs BENCH_serve_sharded.json."""
     from benchmarks.common import regress_gate
-    import jax
-    if len(jax.devices()) < 4:
-        if "REPRO_SERVE_SHARDED_SUBPROC" in os.environ:
-            return [f"serve_sharded: {len(jax.devices())} devices in "
-                    "subprocess, need 4"]
-        rc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.serve_sharded",
-             "--regress"],
-            env=subproc_env("REPRO_SERVE_SHARDED_SUBPROC")).returncode
-        return [] if rc == 0 else \
-            [f"serve_sharded: regress subprocess exited {rc}"]
+    require_devices(4, "serve_sharded")
     want = next(r for r in baseline["modes"]["rank_rule"]["shards"]
                 if r["n_shards"] == 4)
     bs = baseline["batch_size"]

@@ -38,6 +38,7 @@ from repro.core.fleet_dynamics import (ALERT_FRACTION, ALERT_MARGIN_W,
                                        RunParams, inband_step, rapl_step)
 from repro.core.power_model import (F_MAX, N_PSTATES, ServerPowerModel,
                                     dyn_scale, pstate_frequencies)
+from repro.core.rounding import div
 
 __all__ = ["POLL_INTERVAL_S", "ALERT_MARGIN_W", "LIFT_AFTER_S", "N_RAISE",
            "RAPL_STEP_FRAC", "RAISE_HEADROOM_W", "PSU_TRIP_MARGIN_W",
@@ -96,7 +97,7 @@ def apportion_watts(cut_w, dyn_w, floors, xp=np, blind: bool = False):
     if blind:
         total = xp.sum(dyn_w, axis=-1, keepdims=True)
         share = xp.where(total > 0,
-                         dyn_w / xp.where(total > 0, total, 1), 0)
+                         div(dyn_w, xp.where(total > 0, total, 1), xp), 0)
         take = xp.minimum(cut[..., None] * share, red_max)
     else:
         cum = xp.cumsum(red_max, axis=-1) - red_max     # exclusive
@@ -104,7 +105,8 @@ def apportion_watts(cut_w, dyn_w, floors, xp=np, blind: bool = False):
     leftover = xp.maximum(cut - xp.sum(take, axis=-1), 0)
     # invert the reduction table per level: smallest p-state whose
     # reducible fraction covers the level's share (zero-draw guard)
-    ratio = xp.where(dyn_w > 0, take / xp.where(dyn_w > 0, dyn_w, 1), 0)
+    ratio = xp.where(dyn_w > 0,
+                     div(take, xp.where(dyn_w > 0, dyn_w, 1), xp), 0)
     pstate = xp.sum((fracs < ratio[..., None]).astype(np.int32),
                     axis=-1)
     pstate = xp.minimum(pstate, xp.asarray(floors))

@@ -34,6 +34,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro.core.rounding import div
+
 #: Resource-axis order of every (R,) vector in the serve plane.
 RESOURCES = ("watts", "cores", "gb")
 N_RESOURCES = len(RESOURCES)
@@ -107,7 +109,7 @@ def trough_ratios(util, pivot_util: float = 0.55,
     makes temporarily safe to oversell (and the emergency ladder —
     cap, balloon, migrate — backstops when the peak returns)."""
     util = xp.asarray(util)
-    relief = xp.clip((pivot_util - util) / pivot_util, 0.0, 1.0)
+    relief = xp.clip(div(pivot_util - util, pivot_util, xp), 0.0, 1.0)
     one = xp.ones_like(relief)
     return xp.stack([one, one + cores_boost * relief,
                      one + gb_boost * relief], axis=-1)

@@ -12,8 +12,8 @@ Timings use `time.perf_counter` and happen entirely on the host —
 spans wrap the *dispatch* of jitted kernels, not their internals, so
 tracing can never perturb a placement decision. For device-level
 detail, `SpanTracer.jax_profile` brackets a region with
-``jax.profiler.start_trace``/``stop_trace`` (lazily imported; a
-no-op context if the profiler is unavailable in the container).
+``jax.profiler.start_trace``/``stop_trace`` (lazily imported; it
+raises if the profiler cannot start).
 """
 from __future__ import annotations
 
@@ -113,17 +113,13 @@ class SpanTracer:
     def jax_profile(self, log_dir: str):
         """Bracket a region with ``jax.profiler.start_trace(log_dir)``
         / ``stop_trace`` for device-level timelines (view with
-        TensorBoard or Perfetto). Degrades to a no-op if the profiler
-        backend is unavailable in this container."""
-        try:
-            from jax import profiler as _prof
-            _prof.start_trace(log_dir)
-            started = True
-        except Exception:
-            started = False
+        TensorBoard or Perfetto; the trace lands as an ``.xplane.pb``
+        under ``log_dir/plugins/profile/``). A profiler that cannot
+        start raises: a region asked to be traced is never run
+        silently untraced."""
+        from jax import profiler as _prof
+        _prof.start_trace(log_dir)
         try:
             yield
         finally:
-            if started:
-                with contextlib.suppress(Exception):
-                    _prof.stop_trace()
+            _prof.stop_trace()

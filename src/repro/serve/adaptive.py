@@ -56,6 +56,7 @@ from typing import Any, NamedTuple
 import numpy as np
 
 from repro.core.power_model import F_MAX, ServerPowerModel, idle_power
+from repro.core.rounding import div
 
 __all__ = [
     "AdaptiveConfig", "AdaptiveState", "AdaptiveOutputs",
@@ -209,7 +210,8 @@ def _util_from_power(cfg: AdaptiveConfig, rho_lv, power_w, xp):
     rho = xp.sum(rho_lv, axis=-1)
     dyn = xp.maximum(xp.asarray(power_w) - cfg.static_w, 0)
     return xp.where(rho > 0,
-                    dyn / (cfg.p_dyn_per_core * xp.where(rho > 0, rho, 1)),
+                    div(dyn, cfg.p_dyn_per_core
+                        * xp.where(rho > 0, rho, 1), xp),
                     0.0)
 
 
@@ -267,8 +269,8 @@ def adaptive_step(cfg: AdaptiveConfig, st: AdaptiveState, rho_lv,
     flips = xp.sum(
         ((xp.sign(d[..., 1:]) * xp.sign(d[..., :-1])) < 0).astype(
             np.int32), axis=-1)
-    flip_rate = flips.astype(dtype) \
-        / xp.maximum(count - 2, 1).astype(dtype)
+    flip_rate = div(flips.astype(dtype),
+                    xp.maximum(count - 2, 1).astype(dtype), xp)
 
     latest = chrono[..., -1]
     hot_c = (count >= 1) & (latest > dtype.type(cfg.hot_util))
@@ -280,8 +282,8 @@ def adaptive_step(cfg: AdaptiveConfig, st: AdaptiveState, rho_lv,
     n_known = xp.sum(known.astype(np.int32), axis=-1)
     n_stable = xp.sum(stable.astype(np.int32), axis=-1)
     hot = xp.sum(hot_c.astype(np.int32), axis=-1) > 0
-    frac = n_stable.astype(dtype) \
-        / xp.maximum(n_known, 1).astype(dtype)
+    frac = div(n_stable.astype(dtype),
+               xp.maximum(n_known, 1).astype(dtype), xp)
     ratchet = (n_known > 0) & ~hot \
         & (frac >= dtype.type(cfg.ratchet_quorum))
     backoff = hot | ((n_known > 0)

@@ -50,6 +50,7 @@ from typing import Any, NamedTuple
 import numpy as np
 
 from repro.core.capping import reducible_fracs
+from repro.core.rounding import div
 from repro.serve.emergency import (EmergencyConfig, _TOL_W,
                                    util_from_power)
 
@@ -117,7 +118,7 @@ def balloon_demand_w(ecfg: EmergencyConfig, rho_lv, power_w, xp=np):
     # strictly inside the NUF capacity — exact equality would let
     # float rounding tip an epsilon share onto the critical level.
     demand = xp.where(alarm & (deficit > dtype.type(TOL_W)),
-                      (deficit + dtype.type(TOL_W)) * dyn / denom,
+                      div((deficit + dtype.type(TOL_W)) * dyn, denom, xp),
                       dtype.type(0))
     return alarm, demand
 
@@ -160,7 +161,7 @@ def balloon_step(cfg: BallooningConfig, ecfg: EmergencyConfig,
     headroom = xp.maximum(
         dtype.type(cfg.reclaim_frac) * xp.asarray(mem_nuf_gb, dtype)
         - ballooned, 0)
-    want_gb = demand_w / w_per_gb
+    want_gb = div(demand_w, w_per_gb, xp)
     grab = xp.where(mask & alarm, xp.minimum(want_gb, headroom),
                     dtype.type(0))
     release = xp.where(mask & ~alarm, ballooned, dtype.type(0))

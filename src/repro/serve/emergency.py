@@ -63,6 +63,7 @@ from repro.core.fleet_dynamics import (ALERT_FRACTION, ALERT_MARGIN_W,
                                        FREQ_TABLE, LIFT_AFTER_S)
 from repro.core.power_model import (F_MAX, N_PSTATES, ServerPowerModel,
                                     dyn_scale, idle_power)
+from repro.core.rounding import div
 
 #: Criticality levels, in apportionment priority order: level 0
 #: (non-user-facing) absorbs the cut first, level 1 (user-facing /
@@ -202,7 +203,8 @@ def util_from_power(cfg: EmergencyConfig, rho_lv, power_w, xp=np):
     rho = xp.sum(rho_lv, axis=-1)
     dyn = xp.maximum(xp.asarray(power_w) - cfg.static_w, 0)
     return xp.where(rho > 0,
-                    dyn / (cfg.p_dyn_per_core * xp.where(rho > 0, rho, 1)),
+                    div(dyn, cfg.p_dyn_per_core
+                        * xp.where(rho > 0, rho, 1), xp),
                     0.0)
 
 

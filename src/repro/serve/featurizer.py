@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import features as F
+from repro.core.rounding import div
 from repro.sim.telemetry import (
     VM_TYPES, ArrivalBatch, Population, arrival_batch)
 
@@ -94,7 +95,7 @@ def p95_bucket_jnp(p95_util: jnp.ndarray) -> jnp.ndarray:
     boundary exactly — integer-percent inputs, the common telemetry
     case, are f32-representable and bucket identically to the f64
     host."""
-    return jnp.clip(jnp.ceil(p95_util / 25.0) - 1, 0,
+    return jnp.clip(jnp.ceil(div(p95_util, 25.0, jnp)) - 1, 0,
                     F.N_UTIL_BUCKETS - 1).astype(jnp.int32)
 
 
@@ -159,12 +160,13 @@ def featurize(table: SubscriptionTable, subscription: jnp.ndarray,
     seen = in_range & (cnt > 0)
     denom = jnp.maximum(cnt, 1.0)
     aggs = jnp.stack(
-        [table.uf_sum[sub] / denom,
-         table.lived7d_sum[sub] / denom,
+        [div(table.uf_sum[sub], denom, jnp),
+         div(table.lived7d_sum[sub], denom, jnp),
          cnt], -1)                                           # (B, 3)
-    bucket_mix = table.bucket_sum[sub] / denom[:, None]      # (B, 4)
-    util = jnp.stack([table.avg_util_sum[sub] / denom,
-                      table.p95_util_sum[sub] / denom], -1)  # (B, 2)
+    bucket_mix = div(table.bucket_sum[sub], denom[:, None], jnp)  # (B, 4)
+    util = jnp.stack([div(table.avg_util_sum[sub], denom, jnp),
+                      div(table.p95_util_sum[sub], denom, jnp)],
+                     -1)                                     # (B, 2)
     agg_row = jnp.concatenate([aggs, bucket_mix, util], -1)  # (B, 9)
     agg_row = jnp.where(seen[:, None], agg_row, _DEFAULT_ROW[None])
     onehot = jax.nn.one_hot(vm_type_idx, N_VM_TYPES, dtype=jnp.float32)

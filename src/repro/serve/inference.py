@@ -24,13 +24,18 @@ import numpy as np
 
 from repro.core.forest import ObliviousForest
 from repro.core.predictor import CONFIDENCE_GATE, UF, PredictionService
+from repro.core.rounding import div
 from repro.kernels.forest.ops import (
-    normalize_forest_output, pack_forest, predict_packed)
+    normalize_forest_output, pack_forest, pack_matrices, predict_packed)
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class PackedForest(NamedTuple):
     gather: jnp.ndarray      # (F, T*D) one-hot feature gather
     thr: jnp.ndarray         # (1, T*D)
+    pack: jnp.ndarray        # (T*D, T) power-of-two bit-pack
+    expand: jnp.ndarray      # (T, T*2**D) one-hot leaf expansion
     leaf: jnp.ndarray        # (T*2**D, K) flat leaf table
 
 
@@ -62,8 +67,8 @@ class ServiceMeta:
 
 
 def _pack_one(forest: ObliviousForest) -> tuple[PackedForest, ForestMeta]:
-    gather, thr, leaf, t, d, kind = pack_forest(forest)
-    return PackedForest(gather, thr, leaf), ForestMeta(t, d, kind)
+    *operands, t, d, kind = pack_forest(forest)
+    return PackedForest(*operands), ForestMeta(t, d, kind)
 
 
 def pack_service(svc: PredictionService) \
@@ -81,7 +86,8 @@ def pack_service(svc: PredictionService) \
 def _proba_ref(x, pf: PackedForest, meta: ForestMeta):
     """The Pallas kernel's math in plain jnp (XLA path off-TPU)."""
     t, d = meta.n_trees, meta.depth
-    levels = jnp.dot(x, pf.gather, preferred_element_type=jnp.float32)
+    levels = jnp.dot(x, pf.gather, precision=_HIGHEST,
+                     preferred_element_type=jnp.float32)
     bits = (levels > pf.thr).astype(jnp.int32).reshape(-1, t, d)
     weights = (2 ** jnp.arange(d))[::-1]
     leaf_idx = (bits * weights[None, None]).sum(-1)           # (B, T)
@@ -93,9 +99,8 @@ def _proba_ref(x, pf: PackedForest, meta: ForestMeta):
 def _proba_pallas(x, pf: PackedForest, meta: ForestMeta, interpret,
                   block_b=None, block_t=None):
     kw = {} if block_b is None else {"block_b": block_b}
-    return predict_packed(x, pf.gather, pf.thr, pf.leaf, meta.n_trees,
-                          meta.depth, meta.kind, interpret,
-                          block_t=block_t, **kw)
+    return predict_packed(x, *pf, meta.n_trees, meta.depth, meta.kind,
+                          interpret, block_t=block_t, **kw)
 
 
 def _finish(summed, meta: ForestMeta):
@@ -122,7 +127,8 @@ def _measured_fallback() -> str:
     thr = jnp.asarray(rng.normal(size=(1, t * d)).astype(np.float32))
     leaf = jnp.asarray(
         rng.normal(size=(t * (1 << d), k)).astype(np.float32))
-    pf = PackedForest(gather, thr, leaf)
+    pack, expand = (jnp.asarray(m) for m in pack_matrices(t, d))
+    pf = PackedForest(gather, thr, pack, expand, leaf)
     meta = ForestMeta(t, d, "rf")
 
     def timed(fn):
@@ -161,7 +167,8 @@ def _proba4_ref_stacked(x, packed: PackedService, meta: ForestMeta):
     gather = jnp.concatenate([pf.gather for pf in pfs], 1)  # (F, 4*T*D)
     thr = jnp.concatenate([pf.thr for pf in pfs], 1)
     leaf = jnp.stack([pf.leaf.reshape(t, 1 << d, -1) for pf in pfs])
-    levels = jnp.dot(x, gather, preferred_element_type=jnp.float32)
+    levels = jnp.dot(x, gather, precision=_HIGHEST,
+                     preferred_element_type=jnp.float32)
     bits = (levels > thr).astype(jnp.int32).reshape(-1, 4 * t, d)
     weights = (2 ** jnp.arange(d))[::-1]
     leaf_idx = (bits * weights[None, None]).sum(-1) \
@@ -220,4 +227,4 @@ def served_query(packed: PackedService, meta: ServiceMeta,
 
 def bucket_to_p95_jnp(bucket: jnp.ndarray) -> jnp.ndarray:
     """jnp twin of `core.predictor.bucket_to_p95` (bucket midpoint)."""
-    return (bucket.astype(jnp.float32) * 25.0 + 12.5) / 100.0
+    return div(bucket.astype(jnp.float32) * 25.0 + 12.5, 100.0, jnp)

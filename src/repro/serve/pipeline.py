@@ -18,8 +18,9 @@ already specialized).
 
 `ShardedServePipeline` swaps the placement stage for the sharded
 consistent-placement protocol (`serve.sharding`) when the cluster is
-partitioned over a device mesh — everything upstream of placement is
-shard-agnostic and shared.
+partitioned over a device mesh, and on a mesh runs inference with the
+batch split over the devices — every other stage is shard-agnostic
+and shared.
 
 Arrivals and departures enter through the cross-host ingest subsystem
 (`serve.ingest`, DESIGN.md §11): each ingest host owns its own stamped
@@ -915,7 +916,7 @@ class ServePipeline:
         with self._span("featurize"):
             x = featurize_batch(self.table, batch, pad_to=pad_to)
         with self._span("infer"):
-            q = served_query(packed, meta, x, kernel=self._kernel)
+            q = self._query(packed, meta, x)
             is_uf = q["workload_type_used"] == UF
             policy = self.config.policy
             if policy.use_utilization_predictions:
@@ -946,6 +947,10 @@ class ServePipeline:
         self._record_batch(batch, res, raw=raw)
         return res
 
+    def _query(self, packed, meta, x):
+        """Inference stage of one padded micro-batch."""
+        return served_query(packed, meta, x, kernel=self._kernel)
+
     def _place(self, cores, is_uf, p95_eff, valid, mem):
         """Placement stage of one padded micro-batch: run the batched
         Algorithm-1 scan against the cluster state and return the (B,)
@@ -953,8 +958,7 @@ class ServePipeline:
         queued since the last batch ride along fused in front of the
         scan (`placement.place_batch_caps`) — the batch plus a full
         emergency sweep is still one compiled dispatch. The sharded
-        pipeline overrides this single hook — every other serving
-        stage is shard-agnostic."""
+        pipeline overrides this hook and `_query`."""
         if self._pending_caps:
             n_windows = len(self._pending_caps)
             pw, mask, ts = self._stacked_caps()
@@ -1223,9 +1227,11 @@ class ShardedServePipeline(ServePipeline):
     """`ServePipeline` with the cluster state partitioned across a
     ``("shard",)`` device mesh (`serve.sharding`, DESIGN.md §10).
 
-    Featurization and forest inference are shard-agnostic (one batched
-    call; the subscription table is row-partitioned over the mesh when
-    `shard_table` is set); only the placement stage fans out: arrivals
+    Featurization is shard-agnostic (one batched call; the subscription
+    table is row-partitioned over the mesh when `shard_table` is set).
+    On a mesh, forest inference splits the batch over the devices
+    (`sharding.query_sharded`: a Pallas kernel is never partitioned
+    automatically). The placement stage fans out: arrivals
     are routed to their home shard, placed concurrently under the
     reserve/commit token protocol, and spilled cross-shard when the
     home shard rejects them. `cluster_budget_w` sets the global watt
@@ -1295,6 +1301,12 @@ class ShardedServePipeline(ServePipeline):
         self._ratio_prev = np.ones(config.n_shards)
         self.spill_info = {"rounds": 0, "spilled": 0,
                            "spill_admitted": 0}
+
+    def _query(self, packed, meta, x):
+        if self.mesh is None:
+            return super()._query(packed, meta, x)
+        return sharding.query_sharded(packed, meta, x, self._kernel,
+                                      self.mesh)
 
     # -- sharded placement stage -------------------------------------------
     def _place(self, cores, is_uf, p95_eff, valid, mem):

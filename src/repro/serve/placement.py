@@ -71,6 +71,7 @@ import numpy as np
 
 from repro.core.placement import ClusterState, SchedulerPolicy
 from repro.core.resources import N_RESOURCES, lift_caps, lift_pool
+from repro.core.rounding import div
 from repro.serve import emergency
 
 #: `place_batch` outcome codes (in the returned server array).
@@ -123,7 +124,7 @@ def device_state(state: ClusterState, dtype=jnp.float32,
                  mem_gb=None, mem_nuf=None) -> DeviceClusterState:
     """Mirror a host `ClusterState`'s aggregates onto the device.
     `dtype` selects the serving (f32) or equivalence-testing (f64,
-    under `jax.experimental.enable_x64`) arithmetic.
+    under `jax.enable_x64(True)`) arithmetic.
 
     The host state is the watts/cores oracle; the cores axis of
     `res_peak` is derived from its per-server free cores, and the GB
@@ -162,7 +163,7 @@ def fresh_state(n_servers: int, cores_per_server: int,
 
 def score_chassis_batch(state: DeviceClusterState) -> jnp.ndarray:
     """jnp twin of `ClusterState.score_chassis` — (C,)."""
-    return 1.0 - state.rho_peak / jnp.maximum(state.rho_max, 1e-9)
+    return 1.0 - div(state.rho_peak, jnp.maximum(state.rho_max, 1e-9), jnp)
 
 
 def score_server_batch(state: DeviceClusterState, vm_is_uf,
@@ -173,7 +174,7 @@ def score_server_batch(state: DeviceClusterState, vm_is_uf,
     diff = jnp.where(uf[..., None] if uf.ndim else uf,
                      state.gamma_nuf - state.gamma_uf,
                      state.gamma_uf - state.gamma_nuf)
-    return 0.5 * (1.0 + diff / float(cores_per_server))
+    return 0.5 * (1.0 + div(diff, float(cores_per_server), jnp))
 
 
 def _rule_scores(state: DeviceClusterState, policy: SchedulerPolicy,
@@ -182,7 +183,7 @@ def _rule_scores(state: DeviceClusterState, policy: SchedulerPolicy,
     (`core.placement.packing_score`). Rows 1-2 (when the power rule is
     on): Algorithm-1 score for a UF / NUF arrival — both are kept
     because the arriving VM's type flips the eta term."""
-    pack = 1.0 - state.free_cores / cps
+    pack = 1.0 - div(state.free_cores, cps, jnp)
     if not policy.use_power_rule:
         return pack[None]
     kappa = score_chassis_batch(state)[state.chassis_of]
@@ -373,7 +374,7 @@ def _place_batch_single_rule(state, pool, cores, is_uf, p95_eff, mem,
         if no_rule:
             score = jnp.zeros_like(st.free_cores)
         elif pack_only:
-            score = 1.0 - st.free_cores / cps
+            score = 1.0 - div(st.free_cores, cps, jnp)
         else:
             kappa = score_chassis_batch(st)[st.chassis_of]
             eta = score_server_batch(st, uf_i, cps)
@@ -464,7 +465,7 @@ def _place_batch_impl(state: DeviceClusterState, pool, cores, is_uf,
 
         def rw(sr):
             return jnp.where(rw_guard, one,
-                             one - sr.astype(dtype) / denom)
+                             one - div(sr.astype(dtype), denom, jnp))
 
         obj = pw * rw(sr_pack) + qw * rw(sr_pow)
         masked = jnp.where(by_rank, obj, jnp.asarray(-jnp.inf, dtype))
@@ -484,8 +485,8 @@ def _place_batch_impl(state: DeviceClusterState, pool, cores, is_uf,
         # the inverse permutations recompose in closed form. A
         # rejected/failed arrival leaves scores unchanged, so every
         # correction cancels to zero.
-        p_old = 1.0 - st.free_cores / cps
-        p_new_s = 1.0 - st2.free_cores[srv] / cps
+        p_old = 1.0 - div(st.free_cores, cps, jnp)
+        p_new_s = 1.0 - div(st2.free_cores[srv], cps, jnp)
         dcnt0 = _before(p_new_s, srv, p_old, idx).astype(jnp.int32) \
             - _before(p_old[srv], srv, p_old, idx).astype(jnp.int32)
         fresh0 = _before(p_old, idx, p_new_s, srv) \
@@ -496,10 +497,10 @@ def _place_batch_impl(state: DeviceClusterState, pool, cores, is_uf,
         # K-subset twin of `_rule_scores` rows 1-2 (same float ops on
         # the same operands, so the carried table stays bit-identical
         # to a full recompute)
-        kappa2 = 1.0 - st2.rho_peak[ch] \
-            / jnp.maximum(st2.rho_max[ch], 1e-9)
+        kappa2 = 1.0 - div(st2.rho_peak[ch],
+                           jnp.maximum(st2.rho_max[ch], 1e-9), jnp)
         diff = st2.gamma_nuf[delta] - st2.gamma_uf[delta]
-        eta2 = 0.5 * (1.0 + jnp.stack([diff, -diff]) / cps)
+        eta2 = 0.5 * (1.0 + div(jnp.stack([diff, -diff]), cps, jnp))
         new_d = a * kappa2 + (1.0 - a) * eta2            # (2, K)
         old_d = q_prev[:, delta]
         q_prev2 = q_prev.reshape(-1) \
@@ -554,7 +555,7 @@ def place_batch(state: DeviceClusterState, cores: jnp.ndarray,
 
     Arithmetic follows the state dtype: f32 on the serving path, f64
     (bit-equivalent to the numpy rule) when traced under
-    `jax.experimental.enable_x64` with an f64 state — that is how the
+    `jax.enable_x64(True)` with an f64 state — that is how the
     scheduler simulation's serve backend verifies decision
     equivalence."""
     state, servers, _ = _place_batch_impl(

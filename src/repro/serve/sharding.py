@@ -62,14 +62,15 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.placement import SchedulerPolicy
 from repro.core.power_model import F_MAX, ServerPowerModel, idle_power
 from repro.core.resources import (N_RESOURCES, ResourceVector,
                                   lift_caps, lift_pool)
+from repro.core.rounding import div
 from repro.serve import ballooning, emergency
+from repro.serve.inference import PackedService, ServiceMeta, served_query
 from repro.serve.placement import (DeviceClusterState, FAIL_CAPACITY,
                                    SweepCounters, _apply_cap_windows,
                                    _place_batch_impl, remove_batch)
@@ -202,7 +203,7 @@ def shard_state(state: DeviceClusterState, n_shards: int,
         pool = jnp.full((n_shards, N_RESOURCES), jnp.inf, dtype)
     else:
         total = lift_pool(jnp.asarray(pool_total, dtype), xp=jnp)
-        pool = jnp.broadcast_to(total[None, :] / n_shards,
+        pool = jnp.broadcast_to(div(total[None, :], n_shards, jnp),
                                 (n_shards, N_RESOURCES))
     return ShardedState(shards, global_server, global_chassis, shard_of,
                         local_of, cap, pool)
@@ -247,6 +248,29 @@ def shard_mesh(n_shards: int):
     if len(devices) < n_shards:
         return None
     return Mesh(np.asarray(devices[:n_shards]), (SHARD_AXIS,))
+
+
+@lru_cache(maxsize=None)
+def _query_fn(meta: ServiceMeta, kernel: str, mesh):
+    spec = P(SHARD_AXIS)
+
+    def fn(packed, x):
+        return jax.shard_map(
+            lambda pk, xs: served_query(pk, meta, xs, kernel=kernel),
+            mesh=mesh, in_specs=(P(), spec), out_specs=spec,
+            check_vma=False)(packed, x)
+    return jax.jit(fn)
+
+
+def query_sharded(packed: PackedService, meta: ServiceMeta, x,
+                  kernel: str, mesh: Mesh) -> dict:
+    """`served_query` with the batch split over the mesh's shard axis
+    and the model replicated: each device runs the forests on its
+    slice of the batch. A Pallas (Mosaic) kernel is never partitioned
+    automatically, so on a mesh inference must run inside
+    `shard_map`. Rows are independent, so the result equals the
+    one-device `served_query` row for row."""
+    return _query_fn(meta, kernel, mesh)(packed, x)
 
 
 def device_put_sharded_state(sharded: ShardedState,
@@ -338,8 +362,10 @@ def _round_fn(policy: SchedulerPolicy, cps: float, mesh, ecfg=None):
                 res = one_shard(*(sq(a) for a in args))
                 return jax.tree.map(lambda x: x[None], res)
             spec = P(SHARD_AXIS)
-            out = shard_map(per, mesh=mesh, in_specs=(spec,) * n_in,
-                            out_specs=(spec,) * n_out)(*operands)
+            out = jax.shard_map(per, mesh=mesh,
+                                in_specs=(spec,) * n_in,
+                                out_specs=(spec,) * n_out,
+                                check_vma=False)(*operands)
         st2, srv, pool2 = out[:3]
         glob = jnp.take_along_axis(global_server, jnp.maximum(srv, 0),
                                    axis=1)
@@ -621,9 +647,9 @@ def _caps_fn(cfg: emergency.EmergencyConfig, mesh):
             e2, o2 = one_shard(sq(st), sq(em), p1[0], m1[0], t1[0])
             return jax.tree.map(lambda x: x[None], (e2, o2))
         spec = P(SHARD_AXIS)
-        return shard_map(per, mesh=mesh, in_specs=(spec,) * 5,
-                         out_specs=(spec, spec))(shards, emer, pw, mask,
-                                                 ts)
+        return jax.shard_map(per, mesh=mesh, in_specs=(spec,) * 5,
+                             out_specs=(spec, spec),
+                             check_vma=False)(shards, emer, pw, mask, ts)
 
     return jax.jit(fn)
 
@@ -664,8 +690,9 @@ def _adaptive_fn(cfg, mesh):
             a2, o2 = one_shard(sq(st), sq(a1), p1[0], m1[0])
             return jax.tree.map(lambda x: x[None], (a2, o2))
         spec = P(SHARD_AXIS)
-        return shard_map(per, mesh=mesh, in_specs=(spec,) * 4,
-                         out_specs=(spec, spec))(shards, ast, pw, mask)
+        return jax.shard_map(per, mesh=mesh, in_specs=(spec,) * 4,
+                             out_specs=(spec, spec),
+                             check_vma=False)(shards, ast, pw, mask)
 
     return jax.jit(fn)
 
@@ -742,9 +769,10 @@ def _caps_balloon_fn(ecfg: emergency.EmergencyConfig,
             out = one_shard(sq(st), sq(em), sq(b1), p1[0], m1[0], t1[0])
             return jax.tree.map(lambda x: x[None], out)
         spec = P(SHARD_AXIS)
-        return shard_map(per, mesh=mesh, in_specs=(spec,) * 6,
-                         out_specs=(spec,) * 4)(shards, emer, bst, pw,
-                                                mask, ts)
+        return jax.shard_map(per, mesh=mesh, in_specs=(spec,) * 6,
+                             out_specs=(spec,) * 4,
+                             check_vma=False)(shards, emer, bst, pw,
+                                              mask, ts)
 
     return jax.jit(fn)
 

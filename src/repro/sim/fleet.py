@@ -222,6 +222,16 @@ def _offered_util(la: LayoutArrays, trace_t, freq, xp):
     return xp.where(la.nuf_core, _F32(1.0), util)
 
 
+def _dot(a, b, xp):
+    """``a @ b`` in f32 on every backend: the jnp form asks for
+    ``Precision.HIGHEST``, because a TPU's default f32 dot rounds its
+    operands to bf16 and these one-hot reductions carry frequencies."""
+    if xp is np:
+        return a @ b
+    import jax
+    return xp.dot(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def _app_update(la: LayoutArrays, trace_t, freq, backlog, freq_sum,
                 dt, xp):
     """Fluid-queue UF app + fixed-work NUF app (paper §IV-C). Returns
@@ -229,7 +239,7 @@ def _app_update(la: LayoutArrays, trace_t, freq, backlog, freq_sum,
     frequency. Unbatched (one chassis). The NUF integral carry is the
     raw per-core frequency sum; callers reduce it per-VM after the run."""
     freq_flat = freq.reshape(-1)
-    cap = freq_flat[la.uf_core_idx] @ la.uf_compact     # (Vu,)
+    cap = _dot(freq_flat[la.uf_core_idx], la.uf_compact, xp)   # (Vu,)
     lam = trace_t * la.uf_cores
     backlog = xp.clip(backlog + (lam - cap) * _F32(dt), _F32(0.0), cap)
     # closed-loop client pool: bounded in-flight work (backlog <= cap);
@@ -362,7 +372,7 @@ def _scan_one(cp, la, sc, traces):
 
     (_, _, freq_sum), ys = jax.lax.scan(body, (st0, backlog0, fsum0),
                                         traces, unroll=8)
-    integ = (freq_sum @ la.nuf_onehot.T) * jnp.float32(cp.dt)
+    integ = _dot(freq_sum, la.nuf_onehot.T, jnp) * jnp.float32(cp.dt)
     return ys + (integ,)
 
 

@@ -425,7 +425,7 @@ class _EmergencySim:
         if self.use_jax:
             import jax
             import jax.numpy as jnp
-            with jax.experimental.enable_x64():
+            with jax.enable_x64(True):
                 pwj = jnp.asarray(pw)
                 if self.bst is not None:
                     bstj, boutj = self.bal.balloon_step(
@@ -566,7 +566,7 @@ class _AdaptiveSim:
         if self.use_jax:
             import jax
             import jax.numpy as jnp
-            with jax.experimental.enable_x64():
+            with jax.enable_x64(True):
                 stj, _ = adp.adaptive_step(
                     self.cfg, jax.tree.map(jnp.asarray, self.st),
                     jnp.asarray(rho_lv), jnp.asarray(power),
@@ -1028,7 +1028,7 @@ def simulate(policy: SchedulerPolicy, channel: PredictionChannel,
             rrat = trough_ratios(float(tel.diurnal_util(t))) \
                 if sv.diurnal_ratchet else np.ones(N_RESOURCES)
             cap_mult = np.asarray([ratio, rrat[1], rrat[2]], np.float32)
-            with jax.experimental.enable_x64(), span("place"):
+            with jax.enable_x64(True), span("place"):
                 if backend_name == "serve":
                     if obs is not None:
                         obs.registry.counter(

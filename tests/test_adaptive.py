@@ -179,7 +179,7 @@ def test_jnp_twin_bit_equal_to_numpy_oracle(dtype):
     cfg = _cfg(ratio_max=3.0)
     rho = rng.uniform(5.0, 80.0, (C, 2)).astype(dtype)
     stn = init_adaptive(cfg, C, xp=np, dtype=dtype)
-    ctx = jax.experimental.enable_x64() if dtype == np.float64 \
+    ctx = jax.enable_x64(True) if dtype == np.float64 \
         else contextlib_null()
     with ctx:
         stj = jax.tree.map(jnp.asarray, stn)

@@ -60,7 +60,7 @@ def test_jnp_twin_bit_equal_to_numpy_oracle(seed):
         ._replace(ballooned_gb=standing.copy())
     st2_np, out_np = balloon_step(bcfg, cfg, st_np, rho_lv, power,
                                   mem_nuf, mask, np)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         st_j = init_ballooning(C, xp=jnp, dtype=jnp.float64) \
             ._replace(ballooned_gb=jnp.asarray(standing))
         args = (st_j, jnp.asarray(rho_lv), jnp.asarray(power),

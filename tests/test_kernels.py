@@ -65,37 +65,67 @@ def test_forest_kernel_vs_oracle(trainer, kind, n_classes):
 
 @pytest.fixture(scope="module")
 def packed_forest():
+    # 128 trees of depth 4: 32-, 64- and 128-tree tiles all span a
+    # multiple of 128 gather columns, so the sweep covers 1, 2 and 4
+    # tree tiles
     x = RNG.normal(0, 1, (300, 7)).astype(np.float32)
     y = RNG.integers(0, 3, 300)
     y[x[:, 0] > 0.3] = 0
-    f = train_random_forest(x, y, 3, n_trees=12, depth=4)
+    f = train_random_forest(x, y, 3, n_trees=128, depth=4)
     return f, x, f.predict_proba_np(x), pack_forest(f)
 
 
 @pytest.mark.parametrize("block_b", [32, 128])
-@pytest.mark.parametrize("block_t", [1, 2, 3, 4, 6, 12])
+@pytest.mark.parametrize("block_t,tile", [(1, 128), (32, 32), (40, 32),
+                                          (64, 64), (100, 64),
+                                          (128, 128)])
 def test_forest_tiled_kernel_tile_shape_parity(packed_forest, block_b,
-                                               block_t):
+                                               block_t, tile):
     """The (batch, trees) grid tiling is a pure execution-schedule
-    choice: every tile shape must reproduce the untiled oracle
+    choice: every tile shape, with the tile corners of the bit-pack
+    and expansion matrices, must reproduce the ref.py oracle
     bit-for-bit up to float accumulation order."""
-    f, x, p_np, (gather, thr, leaf, t, d, kind) = packed_forest
+    f, x, p_np, (gather, thr, pack, expand, leaf, t, d, kind) = \
+        packed_forest
+    assert resolve_block_t(t, d, block_t) == tile
     b = x.shape[0]
     pad = (-b) % block_b
     xp = jnp.asarray(np.vstack([x, np.zeros((pad, x.shape[1]),
                                             np.float32)]))
-    summed = forest_predict_pallas(xp, gather, thr, leaf, t, d,
-                                   block_b=block_b, block_t=block_t,
-                                   interpret=True)[:b]
+    summed = forest_predict_pallas(xp, gather, thr, pack, expand, leaf,
+                                   t, d, block_b=block_b,
+                                   block_t=block_t, interpret=True)[:b]
+    p_ref = np.asarray(forest_predict_ref(
+        jnp.asarray(x), jnp.asarray(f.feat_idx),
+        jnp.asarray(f.thresholds), jnp.asarray(f.leaf_values), kind))
+    np.testing.assert_allclose(np.asarray(summed) / t, p_ref, atol=1e-5)
     np.testing.assert_allclose(np.asarray(summed) / t, p_np, atol=1e-5)
 
 
+def test_pack_matrices_rebuild_leaf_index(packed_forest):
+    """bits @ P is the bit-packed leaf index and (leaf_idx @ E) == j the
+    one-hot over each tree's leaves."""
+    f, x, _, (gather, thr, pack, expand, leaf, t, d, kind) = \
+        packed_forest
+    bits = (x @ np.asarray(gather) > np.asarray(thr)).astype(np.float32)
+    leaf_idx = bits @ np.asarray(pack)
+    want = (bits.reshape(-1, t, d) * 2.0 ** np.arange(d)[::-1]).sum(-1)
+    np.testing.assert_array_equal(leaf_idx, want)
+    onehot = (leaf_idx @ np.asarray(expand)
+              == np.arange(t << d) % (1 << d))
+    assert (onehot.reshape(-1, t, 1 << d).sum(-1) == 1).all()
+    np.testing.assert_array_equal(
+        onehot.reshape(-1, t, 1 << d).argmax(-1), want)
+
+
 def test_resolve_block_t_clamps_to_divisor():
-    assert resolve_block_t(12, None) == 12
-    assert resolve_block_t(12, 48) == 12
-    assert resolve_block_t(12, 5) == 4      # largest divisor <= 5
-    assert resolve_block_t(12, 1) == 1
-    assert resolve_block_t(7, 3) == 1       # prime ensemble degrades
+    assert resolve_block_t(12, 4, None) == 12
+    assert resolve_block_t(12, 4, 48) == 12
+    assert resolve_block_t(128, 4, 100) == 64   # largest lane-aligned
+    assert resolve_block_t(128, 4, 31) == 128   # none <= 31: whole
+    assert resolve_block_t(48, 6, 24) == 48     # 24*6 not lane-aligned
+    assert resolve_block_t(128, 6, 64) == 64    # 64*6 = 3 * 128
+    assert resolve_block_t(7, 4, 3) == 7        # prime ensemble
 
 
 # --- flash attention -------------------------------------------------------

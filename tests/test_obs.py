@@ -15,6 +15,7 @@ Covers the three pillars and their acceptance invariants:
 """
 import json
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -161,10 +162,27 @@ def test_tracer_records_spans_and_totals():
     assert h.count == 6
 
 
-def test_jax_profile_degrades_to_noop(tmp_path):
+def test_jax_profile_writes_xplane(tmp_path):
     tr = SpanTracer(MetricsRegistry())
-    with tr.jax_profile(str(tmp_path / "trace")):
-        pass                                # must never raise
+    log_dir = tmp_path / "trace"
+    with tr.jax_profile(str(log_dir)):
+        jnp.arange(8.0).sum().block_until_ready()
+    assert list(log_dir.glob("plugins/profile/*/*.xplane.pb"))
+
+
+def test_jax_profile_raises_when_profiler_cannot_start(tmp_path,
+                                                       monkeypatch):
+    from jax import profiler
+
+    def refuse(log_dir, *a, **kw):
+        raise RuntimeError("profiler unavailable")
+    monkeypatch.setattr(profiler, "start_trace", refuse)
+    tr = SpanTracer(MetricsRegistry())
+    ran = []
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with tr.jax_profile(str(tmp_path / "trace")):
+            ran.append(True)
+    assert not ran                          # the region never ran
 
 
 # -- pipeline integration ---------------------------------------------------

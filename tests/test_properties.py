@@ -130,7 +130,7 @@ def test_rank_order_survives_random_interleavings(seed):
     st_np = _fresh_cluster()
     B = 12
     placed: list = []
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dst = device_state(copy.deepcopy(st_np), jnp.float64)
         for _ in range(3):
             cores = rng.choice([1, 2, 4, 8], B).astype(np.float64)

@@ -58,7 +58,7 @@ def test_interleaved_arrivals_departures_migrations(seed):
     B = 24
     placed: list[tuple] = []
     migrants: list[tuple] = []
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dst = device_state(copy.deepcopy(st_np), jnp.float64)
         for _ in range(5):
             n_new = B - len(migrants)
@@ -110,7 +110,7 @@ def test_rank_ties_identical_arrivals():
     B = 16
     cores = np.full(B, 2.0)
     p95 = np.full(B, 0.5)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dst = device_state(copy.deepcopy(st_np), jnp.float64)
         for is_uf in (np.ones(B, bool),
                       np.arange(B) % 2 == 0):    # mixed-type tie round
@@ -127,7 +127,7 @@ def test_full_servers_fail_then_reopen():
     departures reopen capacity and ranks must be consistent again."""
     policy = SchedulerPolicy(alpha=0.8)
     st_np = _fresh(4, 2, 8)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dst = device_state(copy.deepcopy(st_np), jnp.float64)
         cores = np.full(6, 8.0)
         is_uf = np.array([True, False, True, False, True, False])

@@ -222,7 +222,7 @@ def test_place_batch_matches_sequential_choose_x64(policy):
         want.append(-1 if s is None else s)
         if s is not None:
             st_np.place(s, int(cores[i]), float(p95[i]), bool(is_uf[i]))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dst, srvs = place_batch(
             device_state(st, jnp.float64), cores, is_uf, p95,
             np.ones(B, bool), np.full(st.n_chassis, np.inf), policy,
@@ -320,7 +320,7 @@ def test_place_remove_roundtrip_bit_exact_x64():
     cores = np.array([4.0, 8.0])
     uf = np.array([True, False])
     p95 = np.array([0.7318291, 0.2912347])
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dst0 = device_state(st, jnp.float64)
         dst, srvs = place_batch(dst0, cores, uf, p95, np.ones(2, bool),
                                 np.full(st.n_chassis, np.inf),

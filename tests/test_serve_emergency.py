@@ -179,7 +179,7 @@ def test_sharded_scan_matches_numpy_oracle(use_mesh):
                     "device_count=4")
     cfg = _cfg()
     st = _loaded_state(3)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dst = device_state(st, jnp.float64)
         sharded = shard_state(dst, 4)
         emer = init_emergency_sharded(4, 4, dtype=jnp.float64)

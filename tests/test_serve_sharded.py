@@ -107,7 +107,7 @@ def test_one_shard_identical_to_place_batch_x64(policy):
     equivalence suite uses), including the final state."""
     st = _loaded_state(3, n_servers=36, per_chassis=12, n=200)
     cores, uf, p95, valid = _batch(7, 48)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dst, srvs = place_batch(device_state(st, jnp.float64), cores,
                                 uf, p95, valid,
                                 np.full(st.n_chassis, np.inf), policy,
@@ -173,7 +173,7 @@ def test_global_watt_budget_never_exceeded():
     st = _loaded_state(1)
     cores, uf, p95, valid = _batch(2, 64)
     pool_total = 15.0
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         shd = shard_state(device_state(st, jnp.float64), 4,
                           pool_total=pool_total)
         shd, got, _ = place_group_sharded(shd, cores, uf, p95, valid,
@@ -277,7 +277,7 @@ def test_four_shard_failure_rate_tracks_oracle():
 def test_remove_sharded_roundtrip_restores_state_and_pool():
     st = _loaded_state(6)
     pool_total = 200.0
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         shd0 = shard_state(device_state(st, jnp.float64), 4,
                            pool_total=pool_total)
         cores, uf, p95, valid = _batch(9, 16)
