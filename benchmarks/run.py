@@ -43,7 +43,6 @@ DRIVERS = (
      "BENCH_serve_ingest.json"),
     ("serve_emergency", "benchmarks.serve_emergency",
      "BENCH_serve_emergency.json"),
-    ("serve_obs", "benchmarks.serve_obs", "BENCH_serve_obs.json"),
     ("serve_quality", "benchmarks.serve_quality",
      "BENCH_serve_quality.json"),
     ("serve_adaptive", "benchmarks.serve_adaptive",

@@ -20,7 +20,7 @@ Two axes, one artifact (``BENCH_serve_adaptive.json``):
    ``SWEEP_EVERY`` micro-batches (every sweep drives an adaptive
    scan; the cadence is 2x the production stream's every-4), through
    `ShardedServePipeline` with the controller off vs on. Timing uses
-   the alternating best-of discipline from `benchmarks/serve_obs`
+   the alternating best-of discipline of `benchmarks/serve_quality`
    (docs/performance.md), hardened for the short walls here: warm
    both variants once, then alternate off/on keeping the minimum
    wall over ``BEST_OF`` rounds, each wall timing
@@ -240,7 +240,7 @@ def overhead(smoke: bool = False) -> dict:
            "max_overhead_frac": MAX_OVERHEAD_FRAC, "configs": []}
     # warm the jit caches once per variant, then ALTERNATE off/on
     # keeping the best (minimum) wall, each wall timing several
-    # streams back to back — the serve_obs discipline
+    # streams back to back — the serve_quality discipline
     # (docs/performance.md), widened because sub-second walls swing
     # past the 5% bar on a loaded box
     for on in (False, True):

@@ -3,13 +3,12 @@
 
 One axis, one artifact (BENCH_serve_quality.json): arrivals/s through
 `ShardedServePipeline` at 1 and 4 shards with the §14 base bundle
-(registry + audit + tracer — the cost `benchmarks/serve_obs` already
-gates) vs the full §17 bundle (`Observability.full()`: + windowed
+(registry + audit + tracer) vs the full §17 bundle (`Observability.full()`: + windowed
 aggregation + prediction scorecard + SLO monitor + flight recorder),
 over the same emergency-sweep-interleaved stream
 `benchmarks/serve_emergency` drives. The new pillars fold outputs the
-commit `device_get` already fetches, so the acceptance bar matches
-serve_obs: **<5% arrivals/s overhead at 4 shards** (recorded as
+commit `device_get` already fetches, so the acceptance bar is
+**<5% arrivals/s overhead at 4 shards** (recorded as
 ``quality_overhead_frac`` and asserted at measurement time).
 
 ``--smoke`` pushes one small stream per shard count (CI);

@@ -2,7 +2,8 @@
 docs/observability.md).
 
 Renders one `repro.obs.Observability` bundle as a human report — the
-metric catalog with current values, per-stage span timings, SLO
+metric catalog with current values, per-stage span timings and the
+span tree of the last micro-batch, SLO
 burn-rate states with any active alerts, the prediction-quality
 scorecard, flight-recorder incidents, and the most recent audit-trail
 decisions — and writes the machine-readable snapshot (registry JSON +
@@ -23,6 +24,8 @@ from __future__ import annotations
 import argparse
 import json
 
+import numpy as np
+
 from repro.obs import Observability
 
 __all__ = ["render_report", "snapshot_dict", "write_snapshot",
@@ -39,7 +42,8 @@ def _fmt_labels(labels: dict) -> str:
 def render_report(obs: Observability, audit_tail: int = 8) -> str:
     """One multi-section text report of the whole bundle: every
     counter/gauge with its current value, histogram quantiles, span
-    totals from the tracer, per-rule SLO burn rates (active alerts
+    totals and the last micro-batch's span tree from the tracer,
+    per-rule SLO burn rates (active alerts
     flagged), the prediction scorecard, flight-recorder incidents,
     and the trailing audit decisions (`AuditRecord.describe` lines).
     Sections for pillars that are off are omitted."""
@@ -59,6 +63,7 @@ def render_report(obs: Observability, audit_tail: int = 8) -> str:
             mean_ms = 1e3 * total / max(count, 1)
             lines.append(f"  {span:<12} n={count:<8.0f} "
                          f"total={total:.3f}s mean={mean_ms:.2f}ms")
+        lines.extend(_last_batch_tree(obs.tracer))
     if obs.slo is not None:
         lines.append("== slo ==")
         for name, s in sorted(obs.slo.summary().items()):
@@ -98,6 +103,23 @@ def render_report(obs: Observability, audit_tail: int = 8) -> str:
             lines.append("== audit: recent rejections ==")
             lines.extend("  " + r.describe() for r in rej)
     return "\n".join(lines)
+
+
+def _last_batch_tree(tracer) -> list:
+    """The spans of the last micro-batch in the tracer's ring, in order
+    of entry and indented under their parents (a wait span marked)."""
+    rows = tracer.tail(len(tracer))
+    if not len(rows) or rows["batch"].max() < 0:
+        return []
+    batch = rows["batch"].max()
+    lines = [f"== spans of batch {batch} =="]
+    depth = {}
+    for r in np.sort(rows[rows["batch"] == batch], order="seq"):
+        d = depth[int(r["seq"])] = depth.get(int(r["parent"]), -1) + 1
+        wait = "  (wait)" if r["wait"] else ""
+        lines.append(f"  {'  ' * d}{r['name']:<12} "
+                     f"{1e3 * r['dur']:.3f}ms{wait}")
+    return lines
 
 
 def _num(x) -> str:

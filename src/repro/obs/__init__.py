@@ -9,10 +9,11 @@ Pillars, one bundle:
   * `audit` — an `AuditTrail` ring recording one decision tuple per
     arrival (chosen chassis, rule, fail reason, pool state) so a
     capped critical VM can be explained post-hoc.
-  * `tracer` — a `SpanTracer` timing each pipeline stage per batch
-    (ingest -> merge -> featurize -> infer -> place -> commit, plus
-    emergency sweeps and migrations) with an optional
-    ``jax.profiler`` hook.
+  * `tracer` — a `SpanTracer` timing every host phase of the serve
+    path per batch (ingest, merge, featurize, infer, place, commit,
+    departures, the planes, this bundle's own bookkeeping, device
+    reads and the queue wait) as a span tree, each span also a
+    ``serve.*`` annotation of a running ``jax.profiler`` trace.
   * `windows` — a `WindowPlane` of watermark-aligned tumbling/rolling
     time windows and fixed-bucket histograms (`obs.windows`).
   * `quality` — a `PredictionScorecard` joining predictions recorded
@@ -85,9 +86,9 @@ class Observability:
     def full(cls, audit_capacity: int = 4096,
              span_capacity: int = 4096,
              recorder_rows: int = 65536) -> "Observability":
-        """Every pillar on — the configuration the overhead
-        benchmarks (`benchmarks/serve_obs.py`,
-        `benchmarks/serve_quality.py`) measure."""
+        """Every pillar on — the configuration the serve benchmark's
+        cells run (`bench/configs/fig7_cluster.json`) and
+        `benchmarks/serve_quality.py` measures."""
         reg = MetricsRegistry()
         return cls(registry=reg,
                    audit=AuditTrail(capacity=audit_capacity),

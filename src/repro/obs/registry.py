@@ -142,21 +142,22 @@ class Histogram:
         self.lo = float(lo)
         self.base = float(base)
         self.bounds = lo * np.power(base, np.arange(n_buckets))
-        self.counts = np.zeros(n_buckets + 1, np.int64)  # [+inf overflow]
+        self._log_base = math.log(self.base)
+        # a list, not an array: observe runs once per span on the serve
+        # path, and a list item increments faster than an array element
+        self.counts = [0] * (n_buckets + 1)  # [+inf overflow]
         self.sum = 0.0
         self.count = 0
-
-    def _bucket(self, v: float) -> int:
-        if v <= self.lo:
-            return 0
-        k = math.ceil(math.log(v / self.lo) / math.log(self.base))
-        return min(max(k, 0), len(self.bounds))
 
     def observe(self, v: float) -> None:
         """Record one observation (negative values clamp to bucket 0;
         the exact value still lands in `sum`)."""
         v = float(v)
-        self.counts[self._bucket(v)] += 1
+        k = 0
+        if v > self.lo:     # then log(v / lo) >= 0, so k >= 0
+            k = min(math.ceil(math.log(v / self.lo) / self._log_base),
+                    len(self.counts) - 1)
+        self.counts[k] += 1
         self.sum += v
         self.count += 1
 
@@ -183,15 +184,14 @@ class Histogram:
         lab = dict(self.labels)
         lines, cum = [], 0
         for k, c in enumerate(self.counts):
+            cum += c
             if not c:
                 continue
-            cum_k = int(self.counts[:k + 1].sum())
             le = "+Inf" if k == len(self.bounds) \
                 else f"{self.bounds[k]:.6g}"
             key = _label_key({**lab, "le": le})
             lines.append(f"{self.name}_bucket{_render_labels(key)} "
-                         f"{cum_k}")
-            cum = cum_k
+                         f"{cum}")
         if cum != self.count:       # render a closing +Inf bucket
             key = _label_key({**lab, "le": "+Inf"})
             lines.append(f"{self.name}_bucket{_render_labels(key)} "

@@ -157,11 +157,17 @@ class HostQueue:
     at or below the fleet watermark without risking a late
     out-of-order push. Pushing is purely local: no lock, no
     cross-host traffic.
+
+    With a `clock`, each chunk also keeps the clock's reading at its
+    push, and the events taken from it carry that push time (how long
+    an event then waits in the pipeline is measured from it).
     """
 
-    def __init__(self, host_id: int):
+    def __init__(self, host_id: int, clock=None):
         self.host_id = int(host_id)
-        self._chunks: list = []       # [stamps, kind, payload, offset]
+        self.clock = clock
+        # [stamps, kind, payload, offset, push time or None]
+        self._chunks: list = []
         self._last_t = -np.inf
         self._closed = False
         self._n = 0
@@ -219,7 +225,8 @@ class HostQueue:
                 self.heartbeat(t)
             return
         stamps = self._stamp(t, len(batch))
-        self._chunks.append([stamps, kind, batch, 0])
+        pushed = None if self.clock is None else self.clock()
+        self._chunks.append([stamps, kind, batch, 0, pushed])
         self._last_t = float(stamps[-1])
         self._n += len(batch)
 
@@ -250,19 +257,22 @@ class HostQueue:
     def _take(self, up_to: float):
         """Consume this host's window of events with ``t <= up_to``:
         returns (stamps, kind, per-kind payload batches, kind-local
-        index) in push order. Chunks are internally sorted, so the cut
-        is one searchsorted per touched chunk."""
-        ts, kinds, kidx = [], [], []
+        index, push times or None without a clock) in push order.
+        Chunks are internally sorted, so the cut is one searchsorted
+        per touched chunk."""
+        ts, kinds, kidx, pushed = [], [], [], []
         parts = [[] for _ in range(_N_KINDS)]
         counts = [0] * _N_KINDS
         keep = 0
         for chunk in self._chunks:
-            stamps, kind, payload, off = chunk
+            stamps, kind, payload, off, t_push = chunk
             hi = int(np.searchsorted(stamps[off:], up_to, side="right")) \
                 + off
             if hi > off:
                 ts.append(stamps[off:hi])
                 kinds.append(np.full(hi - off, kind, np.int8))
+                if t_push is not None:
+                    pushed.append(np.full(hi - off, t_push))
                 kidx.append(counts[kind] + np.arange(hi - off))
                 parts[kind].append(slice_soa(payload, off, hi))
                 counts[kind] += hi - off
@@ -277,7 +287,8 @@ class HostQueue:
         return (np.concatenate(ts), np.concatenate(kinds),
                 tuple(_concat_soa(cls, p)
                       for cls, p in zip(_KIND_CLS, parts)),
-                np.concatenate(kidx).astype(np.int64))
+                np.concatenate(kidx).astype(np.int64),
+                np.concatenate(pushed) if pushed else None)
 
 
 class MergedEvents(NamedTuple):
@@ -294,6 +305,9 @@ class MergedEvents(NamedTuple):
     arrivals: ArrivalBatch          # arrival-event rows, merged order
     departures: DepartureBatch      # departure-event rows, merged order
     caps: CapBatch                  # power-sample rows, merged order
+    #: (E,) f64 — each event's push time on the mux's clock (None
+    #: without a clock)
+    pushed: np.ndarray | None = None
 
     def __len__(self) -> int:
         return len(self.t)
@@ -378,12 +392,13 @@ class IngestMux:
     `drain` releases everything regardless of watermark (end of
     stream, or a flush). There is no global queue and the merge never
     sorts the full stream — it k-way-merges the K already-sorted host
-    windows."""
+    windows. With a `clock`, released events carry their push times
+    (`MergedEvents.pushed`)."""
 
-    def __init__(self, n_hosts: int = 1):
+    def __init__(self, n_hosts: int = 1, clock=None):
         if n_hosts < 1:
             raise ValueError(f"n_hosts must be >= 1, got {n_hosts}")
-        self.hosts = [HostQueue(h) for h in range(n_hosts)]
+        self.hosts = [HostQueue(h, clock) for h in range(n_hosts)]
 
     @property
     def n_hosts(self) -> int:
@@ -431,10 +446,12 @@ class IngestMux:
         for hid, w in taken:
             if w is None:
                 continue
-            ts, kinds, batches, kidx = w
-            windows.append({"t": ts,
-                            "host": np.full(len(ts), hid, np.int32),
-                            "kind": kinds, "kidx": kidx})
+            ts, kinds, batches, kidx, pushed = w
+            win = {"t": ts, "host": np.full(len(ts), hid, np.int32),
+                   "kind": kinds, "kidx": kidx}
+            if pushed is not None:
+                win["pushed"] = pushed
+            windows.append(win)
             for k in range(_N_KINDS):
                 by_host[k][hid] = batches[k]
         merged = _merge_windows(windows)
@@ -466,7 +483,7 @@ class IngestMux:
             merged["t"], merged["host"], merged["kind"],
             pack(empty_arrivals(), ARRIVAL),
             pack(empty_departures(), DEPARTURE),
-            pack(empty_caps(), CAPPING))
+            pack(empty_caps(), CAPPING), merged.get("pushed"))
 
     def poll(self) -> MergedEvents:
         """Release every event at or below the fleet watermark, in

@@ -282,6 +282,7 @@ class ServePipeline:
         # host-side consumers of outputs the kernels already produce,
         # so obs on/off never changes a decision
         self.obs = planes.obs
+        self._tracer = None if self.obs is None else self.obs.tracer
         # ingest watermark (stamp of the newest drained merged run) —
         # the clock the windows/SLO/recorder pillars (DESIGN.md §17)
         # aggregate on; stays 0.0 until the first streamed event
@@ -316,8 +317,13 @@ class ServePipeline:
             raise ValueError(
                 f"n_ingest_hosts must be >= 1, "
                 f"got {self.config.n_ingest_hosts}")
-        self.ingest = IngestMux(self.config.n_ingest_hosts)
+        # with a tracer, ingest keeps each event's push time: the
+        # `queue` wait span of a batch starts at its oldest arrival's
+        self.ingest = IngestMux(
+            self.config.n_ingest_hosts,
+            clock=None if self._tracer is None else self._tracer.clock)
         self._pending: list[ArrivalBatch] = []   # merged, awaiting batch
+        self._pending_pushed: list[np.ndarray] = []  # their push times
         self._queued = 0
         self.swaps = 0
         self.served = 0
@@ -326,6 +332,8 @@ class ServePipeline:
         self._pending_caps: list[tuple] = []    # queued (chassis, pw, t)
         self.emergency = None
         self._alarms = 0
+        # counters of a fused sweep, fetched with its batch's decisions
+        self._sweep = None          # (SweepCounters, windows) or None
         self._cap_epoch = None      # first cap stamp; rebases clocks
         if self.emergency_cfg is not None:
             ecfg = self.emergency_cfg
@@ -462,7 +470,7 @@ class ServePipeline:
                 and self.obs is not None
                 and self.obs.quality is not None):
             self._ratio_dev = adaptive.gate_ratio_on_stale(
-                self.adaptive_cfg, np.asarray(out.ratio),
+                self.adaptive_cfg, self._fetch(out.ratio),
                 self.obs.quality.model_stale)
         self._refresh_caps()
         self._record_adaptive(out)
@@ -513,28 +521,27 @@ class ServePipeline:
         if self.obs is None:
             return
         reg = self.obs.registry
-        r = float(np.asarray(out.ratio))
+        out = self._fetch(out)
+        r = float(out.ratio)
         reg.gauge("adaptive_ratio",
                   help="oversubscription ratio of the adaptive "
                   "controller").set(r)
         reg.counter("adaptive_ratchet_total",
                     help="adaptive-controller up-steps taken").inc(
-                        int(np.asarray(out.ratchet)))
+                        int(out.ratchet))
         reg.counter("adaptive_backoff_total",
                     help="adaptive-controller down-steps taken").inc(
-                        int(np.asarray(out.backoff)))
+                        int(out.backoff))
         if self.obs.adaptive is not None:
-            ratchet = bool(np.asarray(out.ratchet))
-            backoff = bool(np.asarray(out.backoff))
+            ratchet, backoff = bool(out.ratchet), bool(out.backoff)
             self.obs.adaptive.record(
                 t=time.time(), shard=-1, ratio=r,
-                stable_frac=float(np.asarray(out.stable_frac)),
-                n_known=int(np.asarray(out.n_known)),
-                n_stable=int(np.asarray(out.n_stable)),
+                stable_frac=float(out.stable_frac),
+                n_known=int(out.n_known), n_stable=int(out.n_stable),
                 action=1 if ratchet else (-1 if backoff else 0),
                 reason=adaptive.decision_reason(
-                    self._ratio_prev, r, int(np.asarray(out.n_known)),
-                    ratchet, backoff, bool(np.asarray(out.hot))))
+                    self._ratio_prev, r, int(out.n_known),
+                    ratchet, backoff, bool(out.hot)))
         self._ratio_prev = r
 
     # -- observability (repro.obs, DESIGN.md §14) --------------------------
@@ -555,6 +562,27 @@ class ServePipeline:
             return self.obs.span(name)
         return contextlib.nullcontext()
 
+    def _fetch(self, tree):
+        """Read a pytree of device arrays to the host in one
+        `jax.device_get`, under a ``fetch`` span: every host read of a
+        device value on the unsharded serve path goes through here."""
+        with self._span("fetch"):
+            return jax.device_get(tree)
+
+    @contextlib.contextmanager
+    def _push(self):
+        """One push call: the spans it closes outside a batch's
+        service are attributed to the first micro-batch it serves
+        (they stay -1 when it serves none)."""
+        tr = self._tracer
+        if tr is None:
+            yield
+            return
+        since, first = tr.mark(), self._batches + 1
+        yield
+        if self._batches >= first:
+            tr.claim(since, first)
+
     def _pool_tokens_left(self) -> float:
         """Remaining power tokens recorded into audit rows (+inf when
         no cluster watt budget bounds admission — the unsharded
@@ -573,7 +601,6 @@ class ServePipeline:
         if self.obs is None:
             return
         reg = self.obs.registry
-        self._batches += 1
         b = len(res.server)
         valid = np.ones(b, bool)
         cnt = placement.outcome_counters(
@@ -773,11 +800,12 @@ class ServePipeline:
         with several hosts a batch is only served once every host's
         clock has passed it — push (or `flush`) regularly from all
         hosts to keep the watermark moving."""
-        with self._span("ingest"):
-            self.ingest.submit_to(host, batch, t)
-        with self._span("merge"):
-            events = self.ingest.poll()
-        return self._drain_events(events)
+        with self._push():
+            with self._span("ingest"):
+                self.ingest.submit_to(host, batch, t)
+            with self._span("merge"):
+                events = self.ingest.poll()
+            return self._drain_events(events)
 
     def depart_to(self, host: int, servers, cores, p95_eff, is_uf,
                   t=None, mem_gb=None) -> list[ServeResult]:
@@ -792,17 +820,18 @@ class ServePipeline:
         budget is never exceeded either way). Advancing this host's
         clock can release queued micro-batches — any results are
         returned."""
-        with self._span("ingest"):
-            self.ingest.depart_to(host, DepartureBatch(
-                np.asarray(servers, np.int32),
-                np.asarray(cores, np.float32),
-                np.asarray(p95_eff, np.float32),
-                np.asarray(is_uf, bool),
-                None if mem_gb is None
-                else np.asarray(mem_gb, np.float32)), t)
-        with self._span("merge"):
-            events = self.ingest.poll()
-        return self._drain_events(events)
+        with self._push():
+            with self._span("ingest"):
+                self.ingest.depart_to(host, DepartureBatch(
+                    np.asarray(servers, np.int32),
+                    np.asarray(cores, np.float32),
+                    np.asarray(p95_eff, np.float32),
+                    np.asarray(is_uf, bool),
+                    None if mem_gb is None
+                    else np.asarray(mem_gb, np.float32)), t)
+            with self._span("merge"):
+                events = self.ingest.poll()
+            return self._drain_events(events)
 
     def cap_to(self, host: int, chassis, power_w,
                t=None) -> list[ServeResult]:
@@ -819,26 +848,32 @@ class ServePipeline:
             raise ValueError(
                 "cap_to() needs a pipeline built with emergency_cfg "
                 "or adaptive_cfg")
-        with self._span("ingest"):
-            self.ingest.cap_to(host, CapBatch(
-                np.asarray(chassis, np.int32),
-                np.asarray(power_w, np.float32)), t)
-        with self._span("merge"):
-            events = self.ingest.poll()
-        return self._drain_events(events)
+        with self._push():
+            with self._span("ingest"):
+                self.ingest.cap_to(host, CapBatch(
+                    np.asarray(chassis, np.int32),
+                    np.asarray(power_w, np.float32)), t)
+            with self._span("merge"):
+                events = self.ingest.poll()
+            return self._drain_events(events)
 
     def flush(self) -> ServeResult | None:
         """Serve everything still queued, watermark ignored (padded up
         to the batch size; chunked if the drain releases more than one
         micro-batch). Returns one concatenated result, or None."""
-        with self._span("merge"):
-            events = self.ingest.drain()
-        out = self._drain_events(events)
-        if self._queued:
-            merged = _concat_batches(self._pending)
-            self._pending, self._queued = [], 0
-            out.append(self._serve_padded(merged))
-        self._flush_caps()          # trailing caps with no batch to ride
+        with self._push():
+            with self._span("merge"):
+                events = self.ingest.drain()
+            out = self._drain_events(events)
+            if self._queued:
+                merged = _concat_batches(self._pending)
+                self._queue_wait(0, len(merged))
+                self._pending, self._queued = [], 0
+                self._pending_pushed = []
+                out.append(self._serve_padded(merged))
+            if self._pending_caps:  # trailing caps with no batch to ride
+                with self._span("cap"):
+                    self._flush_caps()
         if not out:
             return None
         return out[0] if len(out) == 1 else _concat_results(out)
@@ -854,7 +889,8 @@ class ServePipeline:
         rec = None if self.obs is None else self.obs.recorder
         pos = 0
         for kind, lo, hi in events.runs():
-            t_run = events.t[pos:pos + (hi - lo)]
+            run = slice(pos, pos + (hi - lo))
+            t_run = events.t[run]
             pos += hi - lo
             if len(t_run):
                 # the merged stream is the watermark clock the §17
@@ -864,31 +900,47 @@ class ServePipeline:
                 caps = slice_soa(events.caps, lo, hi)
                 if rec is not None:
                     rec.record_caps(t_run, caps)
-                self._apply_caps(caps, t_run)
+                with self._span("cap"):
+                    self._apply_caps(caps, t_run)
                 continue
             if kind != ARRIVAL:
                 d = slice_soa(events.departures, lo, hi)
                 if rec is not None:
                     rec.record_departures(t_run, d)
-                self._apply_departures(d.server, d.cores, d.p95_eff,
-                                       d.is_uf, d.mem_gb)
+                with self._span("depart"):
+                    self._apply_departures(d.server, d.cores, d.p95_eff,
+                                           d.is_uf, d.mem_gb)
                 continue
             arr = slice_soa(events.arrivals, lo, hi)
             if rec is not None:
                 rec.record_arrivals(t_run, arr)
             self._pending.append(arr)
+            if events.pushed is not None:
+                self._pending_pushed.append(events.pushed[run])
             self._queued += hi - lo
             if self._queued < bs:
                 continue
             merged = _concat_batches(self._pending)  # one copy, slice
             start = 0
             while self._queued - start >= bs:
+                self._queue_wait(start, start + bs)
                 out.append(self._serve_padded(
                     slice_soa(merged, start, start + bs)))
                 start += bs
             self._pending = [slice_soa(merged, start, len(merged))]
+            if self._pending_pushed:
+                self._pending_pushed = [
+                    np.concatenate(self._pending_pushed)[start:]]
             self._queued = self._queued - start
         return out
+
+    def _queue_wait(self, lo: int, hi: int) -> None:
+        """Record the ``queue`` wait span of the batch about to be
+        served from the pending arrivals [lo, hi): from the push of the
+        oldest of them to now (no-op without a tracer)."""
+        if self._pending_pushed:
+            t0 = float(np.concatenate(self._pending_pushed)[lo:hi].min())
+            self._tracer.record_wait("queue", t0, batch=self._batches + 1)
 
     def serve(self, batch: ArrivalBatch) -> ServeResult:
         """Serve one batch synchronously, bypassing the queue (chunks
@@ -910,6 +962,19 @@ class ServePipeline:
             self._recorder_suspended = False
 
     def _serve_padded(self, batch: ArrivalBatch) -> ServeResult:
+        """Serve one micro-batch (padded to the batch size) under the
+        next batch sequence number, the batch its spans are given."""
+        self._batches += 1
+        tr = self._tracer
+        if tr is None:
+            return self._serve_batch(batch)
+        tr.batch = self._batches
+        try:
+            return self._serve_batch(batch)
+        finally:
+            tr.batch = -1
+
+    def _serve_batch(self, batch: ArrivalBatch) -> ServeResult:
         b = len(batch)
         pad_to = self.config.batch_size
         packed, meta = self._buffers[self._active]
@@ -933,18 +998,26 @@ class ServePipeline:
         self.served += b
         with self._span("commit"):
             # the quality pillar also wants the raw (ungated) head
-            # outputs + confidences — fetched in the same device_get,
-            # outputs only, so decisions are untouched either way
-            fetch = (servers, q["workload_type_used"],
-                     q["p95_bucket_used"], p95_eff, q["conservative"])
-            score = self.obs is not None and self.obs.quality is not None
-            if score:
-                fetch += (q["workload_type"], q["workload_conf"],
-                          q["p95_bucket"], q["p95_conf"])
-            host = jax.device_get(fetch)
-        res = ServeResult(*(a[:b] for a in host[:5]))
-        raw = tuple(a[:b] for a in host[5:]) if score else None
-        self._record_batch(batch, res, raw=raw)
+            # outputs + confidences, and a fused sweep its counters —
+            # fetched in the same device_get, outputs only, so
+            # decisions are untouched either way
+            dec = (servers, q["workload_type_used"],
+                   q["p95_bucket_used"], p95_eff, q["conservative"])
+            raw = None
+            if self.obs is not None and self.obs.quality is not None:
+                raw = (q["workload_type"], q["workload_conf"],
+                       q["p95_bucket"], q["p95_conf"])
+            sweep, self._sweep = self._sweep, None
+            dec, raw, counters = self._fetch(
+                (dec, raw, None if sweep is None else sweep[0]))
+        res = ServeResult(*(a[:b] for a in dec))
+        with self._span("record"):
+            if sweep is not None:
+                self._alarms += int(counters.alarms)
+                self._record_sweep(counters, windows=sweep[1])
+            self._record_batch(
+                batch, res,
+                raw=None if raw is None else tuple(a[:b] for a in raw))
         return res
 
     def _query(self, packed, meta, x):
@@ -957,8 +1030,9 @@ class ServePipeline:
         server decisions (FAIL_* codes on reject). Cap sub-windows
         queued since the last batch ride along fused in front of the
         scan (`placement.place_batch_caps`) — the batch plus a full
-        emergency sweep is still one compiled dispatch. The sharded
-        pipeline overrides this hook and `_query`."""
+        emergency sweep is still one compiled dispatch, whose counters
+        are fetched with the batch's decisions. The sharded pipeline
+        overrides this hook and `_query`."""
         if self._pending_caps:
             n_windows = len(self._pending_caps)
             pw, mask, ts = self._stacked_caps()
@@ -974,8 +1048,7 @@ class ServePipeline:
                 is_uf, p95_eff, valid, self.res_cap,
                 self.config.policy, self.cores_per_server,
                 self.emergency_cfg, mem_gb=mem)
-            self._alarms += int(np.asarray(sweep.alarms))
-            self._record_sweep(sweep, windows=n_windows)
+            self._sweep = (sweep, n_windows)
             return servers
         if self.obs is not None:
             self.obs.registry.counter(
@@ -1012,7 +1085,8 @@ class ServePipeline:
                 "depart() is the single-queue (1-host) path; with "
                 f"n_ingest_hosts={self.config.n_ingest_hosts} use "
                 "depart_to(host, ..., t=...)")
-        self._apply_departures(servers, cores, p95_eff, is_uf, mem_gb)
+        with self._span("depart"):
+            self._apply_departures(servers, cores, p95_eff, is_uf, mem_gb)
 
     def _apply_departures(self, servers, cores, p95_eff, is_uf,
                           mem_gb=None) -> None:
@@ -1078,23 +1152,31 @@ class ServePipeline:
         pending, self._pending_caps = self._pending_caps, []
         for chassis, power_w, t in pending:
             with self._span("emergency"):
-                out = self._cap_window(chassis, power_w, t)
-            alarms = int(np.asarray(out.alarm).sum())
+                out, bout = self._cap_window(chassis, power_w, t)
+            if self.obs is None:
+                self._alarms += int(self._fetch(out.alarm).sum())
+                continue
+            alarm, cut_w, leftover_w, cbl, bal = self._fetch((
+                out.alarm, out.cut_w, out.leftover_w, out.cut_by_level_w,
+                None if bout is None else (bout,
+                                           self._balloon.ballooned_gb)))
+            alarms = int(alarm.sum())
             self._alarms += alarms
-            if self.obs is not None:
-                cbl = np.asarray(out.cut_by_level_w, np.float64)
-                self._record_sweep(placement.SweepCounters(
-                    samples=len(chassis), alarms=alarms,
-                    cut_w=np.asarray(out.cut_w, np.float64).sum(),
-                    leftover_w=np.asarray(out.leftover_w,
-                                          np.float64).sum(),
-                    cut_by_level_w=cbl.reshape(
-                        -1, emergency.N_LEVELS).sum(0)), windows=1)
+            if bal is not None:
+                self._record_balloon(*bal)
+            self._record_sweep(placement.SweepCounters(
+                samples=len(chassis), alarms=alarms,
+                cut_w=np.asarray(cut_w, np.float64).sum(),
+                leftover_w=np.asarray(leftover_w, np.float64).sum(),
+                cut_by_level_w=np.asarray(cbl, np.float64).reshape(
+                    -1, emergency.N_LEVELS).sum(0)), windows=1)
 
     def _cap_window(self, chassis, power_w, t):
         """Apply one unique-chassis sample window (unsharded path) —
         through the balloon-then-cap kernel when the ballooning rung is
-        attached, the plain emergency kernel otherwise."""
+        attached, the plain emergency kernel otherwise. Returns the
+        emergency and balloon outputs (None without the rung), still
+        on the device."""
         dtype = self.state.free_cores.dtype
         pw, mask, ts = emergency.scatter_samples(
             self.n_chassis, chassis, power_w, t, jnp, dtype)
@@ -1110,8 +1192,7 @@ class ServePipeline:
              bout) = fn(self.state.gamma_nuf, self.state.gamma_uf,
                         self.state.chassis_servers, self.state.mem_nuf,
                         self._emergency, self._balloon, pw, mask, ts)
-            self._record_balloon(bout)
-            return out
+            return out, bout
         if self.obs is not None:
             self.obs.registry.counter(
                 "serve_dispatch_total",
@@ -1122,7 +1203,7 @@ class ServePipeline:
                                   self.state.gamma_uf,
                                   self.state.chassis_servers,
                                   self._emergency, pw, mask, ts)
-        return out
+        return out, None
 
     # -- ballooning rung (serve.ballooning) --------------------------------
     @property
@@ -1141,12 +1222,10 @@ class ServePipeline:
         self._flush_caps()
         return ballooning.total_ballooned_gb(self._balloon)
 
-    def _record_balloon(self, bout) -> None:
-        """Export one balloon sweep's outputs: reclaim/release/absorb
-        counters and the standing-balloon gauge — host-side reductions
-        of outputs the kernel already returned."""
-        if self.obs is None:
-            return
+    def _record_balloon(self, bout, ballooned_gb) -> None:
+        """Export one balloon sweep's outputs (fetched to the host):
+        reclaim/release/absorb counters and the standing-balloon gauge
+        (`ballooned_gb`, the state's per-chassis balloons after it)."""
         reg = self.obs.registry
         reg.counter("balloon_reclaimed_gb_total",
                     help="GB ballooned out of NUF VMs").inc(
@@ -1166,7 +1245,7 @@ class ServePipeline:
                         int(np.asarray(bout.inflated).sum()))
         reg.gauge("balloon_ballooned_gb",
                   help="fleet GB currently ballooned out").set(
-                      ballooning.total_ballooned_gb(self._balloon))
+                      float(np.asarray(ballooned_gb).sum()))
 
     def throttled_by_level(self) -> np.ndarray:
         """(L,) cumulative throttled-seconds per criticality level
@@ -1538,7 +1617,8 @@ class ShardedServePipeline(ServePipeline):
         """Apply one unique-chassis sample window: route samples to
         their owner shards and run every shard's alarm + apportionment
         kernel concurrently (vmap, or shard_map on the mesh) — with
-        the ballooning rung in front when attached."""
+        the ballooning rung in front when attached. Returns the
+        emergency and balloon outputs (None without the rung)."""
         if self._balloon is not None:
             if self.obs is not None:
                 self.obs.registry.counter(
@@ -1550,8 +1630,7 @@ class ShardedServePipeline(ServePipeline):
                 self.emergency_cfg, self.config.planes.ballooning,
                 self.sharded, self._emergency, self._balloon, chassis,
                 power_w, t, mesh=self.mesh)
-            self._record_balloon(bout)
-            return out
+            return out, bout
         if self.obs is not None:
             self.obs.registry.counter(
                 "serve_dispatch_total",
@@ -1560,7 +1639,7 @@ class ShardedServePipeline(ServePipeline):
         self._emergency, out = sharding.apply_caps_sharded(
             self.emergency_cfg, self.sharded, self._emergency, chassis,
             power_w, t, mesh=self.mesh)
-        return out
+        return out, None
 
     def _dwell_mask(self, mask: np.ndarray) -> np.ndarray:
         return mask.reshape(self.config.n_shards, -1)

@@ -24,7 +24,9 @@ from repro.core.placement import ClusterState, SchedulerPolicy
 from repro.core.predictor import train_service
 from repro.obs import (AuditTrail, LEVEL_NAMES, MetricsRegistry,
                        Observability, SpanTracer, record_sim_metrics)
-from repro.serve import (CRIT_NUF, CRIT_UF, EmergencyConfig,
+from repro.obs.tracing import OUTERMOST
+from repro.serve import (CRIT_NUF, CRIT_UF, AdaptiveConfig,
+                         BallooningConfig, EmergencyConfig,
                          PlaneBundle, ResourceVector,
                          ServeConfig, ServePipeline, ShardedServeConfig,
                          ShardedServePipeline, device_state, emergency)
@@ -162,27 +164,81 @@ def test_tracer_records_spans_and_totals():
     assert h.count == 6
 
 
-def test_jax_profile_writes_xplane(tmp_path):
-    tr = SpanTracer(MetricsRegistry())
-    log_dir = tmp_path / "trace"
-    with tr.jax_profile(str(log_dir)):
-        jnp.arange(8.0).sum().block_until_ready()
-    assert list(log_dir.glob("plugins/profile/*/*.xplane.pb"))
+class _Clock:
+    """A host clock the test sets by hand."""
+
+    def __init__(self, t=0.0):
+        self.t = t
+
+    def __call__(self):
+        return self.t
 
 
-def test_jax_profile_raises_when_profiler_cannot_start(tmp_path,
-                                                       monkeypatch):
-    from jax import profiler
+def test_span_tree_parent_batch_and_outermost_total():
+    clk = _Clock()
+    tr = SpanTracer(MetricsRegistry(), clock=clk)
+    with tr.span("a"):                      # id 0, outermost
+        clk.t = 1.0
+        with tr.span("b"):                  # id 1, under a
+            clk.t = 3.0
+        tr.batch = 7
+        with tr.span("c"):                  # id 2, under a, batch 7
+            clk.t = 4.0
+        tr.batch = -1
+        clk.t = 5.0
+    tr.record_wait("queue", 2.0)            # id 3: a wait, 3 s
+    with tr.span("d"):                      # id 4, outermost
+        clk.t = 6.0
+    rows = {str(r["name"]): r for r in tr.tail(8)}
+    assert [int(rows[n]["parent"]) for n in "abcd"] == [-1, 0, 0, -1]
+    assert [int(rows[n]["batch"]) for n in "abcd"] == [-1, -1, 7, -1]
+    assert rows["queue"]["wait"] and not rows["a"]["wait"]
+    assert rows["queue"]["dur"] == 3.0 and rows["a"]["dur"] == 5.0
+    totals = tr.totals()
+    # a (5 s) and d (1 s): nested b, c and the wait are left out
+    assert totals[OUTERMOST] == (2, 6.0)
+    assert totals["queue"] == (1, 3.0)
+    assert totals["b"] == (1, 2.0)
+    # the spans a push closed outside a batch go to the batch it served
+    since = tr.mark()
+    with tr.span("ingest"):
+        pass
+    tr.batch = 8
+    with tr.span("commit"):
+        pass
+    tr.batch = -1
+    tr.claim(since, 8)
+    rows = tr.tail(2)
+    assert list(rows["name"]) == ["ingest", "commit"]
+    assert list(rows["batch"]) == [8, 8]
+    assert rows["seq"].tolist() == [5, 6]
 
-    def refuse(log_dir, *a, **kw):
-        raise RuntimeError("profiler unavailable")
-    monkeypatch.setattr(profiler, "start_trace", refuse)
-    tr = SpanTracer(MetricsRegistry())
-    ran = []
-    with pytest.raises(RuntimeError, match="profiler unavailable"):
-        with tr.jax_profile(str(tmp_path / "trace")):
-            ran.append(True)
-    assert not ran                          # the region never ran
+
+def test_spans_land_in_the_profiler_trace(tmp_path, obs_world):
+    """Under a running profiler every stage's span is a ``serve.*``
+    host event of the trace, nested inside the caller's annotation."""
+    import jax
+    svc, table, arrivals = obs_world
+    obs = Observability.full()
+    pipe = _planes_pipe(svc, table, obs)
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("caller"):
+            _drive_planes(pipe, arrivals)
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    events = [(e.name, e.start_ns, e.end_ns)
+              for plane in jax.profiler.ProfileData.from_file(
+                  str(path)).planes if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name == "caller" or e.name.startswith("serve.")]
+    outer = [e for e in events if e[0] == "caller"]
+    assert len(outer) == 1
+    serve = [e for e in events if e[0] != "caller"]
+    assert {e[0] for e in serve} == {
+        "serve." + n for n in ("ingest", "merge", "featurize", "infer",
+                               "place", "commit", "fetch", "record",
+                               "depart", "cap", "emergency")}
+    assert all(outer[0][1] <= s and e <= outer[0][2]
+               for _, s, e in serve)
 
 
 # -- pipeline integration ---------------------------------------------------
@@ -238,6 +294,56 @@ def _pipe(svc, table, obs=None, sharded=False, budget=None):
                                             planes=planes), **kw)
 
 
+def _planes_pipe(svc, table, obs=None):
+    """Two ingest hosts, every plane: emergency, ballooning (its cap
+    windows flush eagerly) and the adaptive controller."""
+    planes = PlaneBundle(
+        emergency=EmergencyConfig.from_model(BUDGET_TIGHT),
+        ballooning=BallooningConfig(), adaptive=AdaptiveConfig(), obs=obs)
+    return ServePipeline(svc, table, device_state(_loaded_state()),
+                         cores_per_server=40, blades_per_chassis=12,
+                         config=ServeConfig(batch_size=32, n_ingest_hosts=2,
+                                            planes=planes))
+
+
+#: power sweeps of `_drive_planes`, one unique-chassis window each
+PLANE_SWEEPS = 3
+
+
+def _drive_planes(pipe, arrivals):
+    """A 2-host stream: arrivals dealt to both hosts, three power
+    sweeps (one sample per chassis each) and one departure chunk, then
+    a flush. Returns every `ServeResult`, in order."""
+    out, n = [], 0
+    for k in range(PLANE_SWEEPS):
+        t0 = 100.0 * k
+        for h in (0, 1):
+            out += pipe.submit_to(
+                h, _first_n(_skip(arrivals, n), 24),
+                t=t0 + h + 2.0 * np.arange(24, dtype=np.float64))
+            n += 24
+        out += pipe.cap_to(k % 2, [0, 1, 2, 3], [2200.0, 1500.0, 2100.0,
+                                                 1700.0],
+                           t=t0 + 60.0 + np.arange(4.0))
+        if k == 1:
+            # the first batch: the hosts' first 16 arrivals each, in
+            # stamp order
+            first = out[0]
+            idx = np.arange(48).reshape(2, 24)[:, :16].T.ravel()
+            adm = np.flatnonzero(first.server >= 0)[:6]
+            out += pipe.depart_to(
+                0, first.server[adm], np.asarray(arrivals.cores)[idx][adm],
+                first.p95_eff[adm], first.workload_type[adm] == 1,
+                t=t0 + 70.0 + np.arange(len(adm), dtype=np.float64))
+    tail = pipe.flush()
+    return out + ([] if tail is None else [tail])
+
+
+def _skip(batch, n):
+    return type(batch)(*(getattr(batch, f)[n:]
+                         for f in type(batch).__dataclass_fields__))
+
+
 def _drive(pipe, arrivals):
     """One deterministic stream: caps, two micro-batches, departures,
     flush. Returns every `ServeResult` produced, in order."""
@@ -279,6 +385,97 @@ def test_metrics_on_is_decision_bit_identical(obs_world, sharded):
                               np.asarray(b.p95_eff))
     # the emergency plane evolved identically too
     assert on.alarms == off.alarms
+    # ... under every span the stream's path opens
+    assert {"ingest", "merge", "featurize", "infer", "place", "commit",
+            "fetch", "record", "depart", "queue"} \
+        <= set(on.obs.tracer.totals())
+
+
+def test_every_plane_on_is_decision_bit_identical(obs_world):
+    """Tracing and the rest of the bundle on against off, two hosts
+    and every plane: the same decisions and the same plane state."""
+    import jax
+    svc, table, arrivals = obs_world
+    on = _planes_pipe(svc, table, Observability.full())
+    off = _planes_pipe(svc, table)
+    res_on, res_off = _drive_planes(on, arrivals), \
+        _drive_planes(off, arrivals)
+    assert len(res_on) == len(res_off) == 5
+    for a, b in zip(res_on, res_off):
+        assert np.array_equal(a.server, b.server)
+        assert np.array_equal(a.p95_eff, b.p95_eff)
+    for a, b in zip(jax.tree.leaves(jax.device_get(
+            (on.emergency, on.balloon_state, on.adaptive_state,
+             on.state))),
+            jax.tree.leaves(jax.device_get(
+                (off.emergency, off.balloon_state, off.adaptive_state,
+                 off.state)))):
+        assert np.array_equal(a, b)
+    assert on.alarms == off.alarms
+    assert {"depart", "cap", "emergency", "record", "fetch", "queue"} \
+        <= set(on.obs.tracer.totals())
+
+
+def test_fetch_count_follows_batches_and_sweeps(obs_world):
+    """One ``fetch`` per batch (the commit) and, per power sweep, one
+    for the adaptive controller's outputs and one for the balloon-cap
+    window's: every host read of the stream is one of those."""
+    svc, table, arrivals = obs_world
+    obs = Observability.full()
+    pipe = _planes_pipe(svc, table, obs)
+    results = _drive_planes(pipe, arrivals)
+    totals, v = obs.tracer.totals(), obs.registry.value
+    batches = -(-3 * 48 // 32)
+    assert len(results) == v("serve_batches_total") == batches
+    assert v("serve_dispatch_total", kind="adaptive_step") \
+        == v("serve_dispatch_total", kind="balloon_cap_step") \
+        == PLANE_SWEEPS
+    assert totals["fetch"][0] == batches + 2 * PLANE_SWEEPS
+    assert totals["commit"][0] == totals["record"][0] == batches
+    assert totals["cap"][0] == PLANE_SWEEPS
+    assert totals["emergency"][0] == PLANE_SWEEPS
+    assert totals["depart"][0] == 1
+    # the work spans' total counts each outermost span once
+    outer = ("ingest", "merge", "featurize", "infer", "place", "commit",
+             "record", "depart", "cap")
+    assert totals[OUTERMOST][0] == sum(totals[n][0] for n in outer)
+    assert totals[OUTERMOST][1] == pytest.approx(
+        sum(totals[n][1] for n in outer))
+
+
+def test_queue_wait_spans_the_watermark_hold(obs_world):
+    """A batch's ``queue`` span runs from the push of its oldest
+    arrival to its release: here host 0's batch waits, held by the
+    watermark, until host 1 first pushes."""
+    svc, table, arrivals = obs_world
+    clk = _Clock(10.0)
+    reg = MetricsRegistry()
+    obs = Observability(registry=reg, tracer=SpanTracer(reg, clock=clk))
+    pipe = ServePipeline(svc, table, device_state(_loaded_state()),
+                         cores_per_server=40, blades_per_chassis=12,
+                         config=ServeConfig(batch_size=32, n_ingest_hosts=2,
+                                            planes=PlaneBundle(obs=obs)))
+    stamps = np.arange(32, dtype=np.float64) + 1.0
+    assert pipe.submit_to(0, _first_n(arrivals, 16), t=stamps[:16]) == []
+    clk.t = 12.0
+    assert pipe.submit_to(0, _first_n(_skip(arrivals, 16), 16),
+                          t=stamps[16:]) == []
+    clk.t = 15.0
+    out = pipe.submit_to(1, _first_n(_skip(arrivals, 32), 8),
+                         t=np.arange(8, dtype=np.float64) + 40.0)
+    assert len(out) == 1 and len(out[0].server) == 32
+    rows = obs.tracer.tail(64)
+    queue = rows[rows["name"] == "queue"]
+    assert len(queue) == 1
+    assert queue["dur"][0] == 5.0           # pushed at 10, released at 15
+    assert queue["wait"][0] and queue["batch"][0] == 1
+    assert queue["parent"][0] == -1
+    # the push that released the batch is attributed to it; the two
+    # pushes that served none are not
+    ingest = rows[rows["name"] == "ingest"]
+    assert ingest["batch"].tolist() == [-1, -1, 1]
+    # no clock moved inside a call: work spans read 0, the wait 5
+    assert obs.tracer.totals()[OUTERMOST][1] == 0.0
 
 
 @pytest.mark.parametrize("sharded", [False, True],
@@ -477,6 +674,12 @@ def test_monitor_report_and_snapshot(tmp_path, obs_world):
     report = monitor.render_report(obs)
     assert "== metrics ==" in report
     assert "== spans ==" in report
+    # the last batch's spans in order of entry, nested under parents
+    tree = report.split("== spans of batch 2 ==\n")[1].splitlines()
+    assert [ln.split()[0] for ln in tree[:7]] == [
+        "queue", "featurize", "infer", "place", "commit", "fetch",
+        "record"]
+    assert tree[0].endswith("(wait)") and tree[5].startswith("    fetch")
     assert "serve_arrivals_total" in report
     assert "== audit" in report
     path = tmp_path / "snap.json"
