@@ -2,8 +2,10 @@
 
 Every host phase of the serve path runs under a `Span`: ``ingest``,
 ``merge``, ``featurize``, ``infer``, ``place``, ``commit``, ``depart``
-(departures and the cap flush in front of them), ``cap`` (the power
-planes' dispatches, with ``emergency`` nested per sample window),
+(a departure run's cap flush and host-side gathering, and in front of
+a batch's placement the gathered removal, one ``remove`` per dispatch
+nested in it), ``cap`` (the power planes' dispatches, with
+``emergency`` nested per sample window),
 ``record`` (the observability pillars' per-batch bookkeeping) and
 ``fetch`` (every host read of a device array), plus ``migrate`` in the
 simulator. A span records its name, start, duration, its parent (the

@@ -196,6 +196,23 @@ def _balloon_cap_step_fn(ecfg: emergency.EmergencyConfig,
     return jax.jit(fn)
 
 
+#: Row counts a gathered departure dispatch is padded to (the smallest
+#: that holds the buffer); a larger buffer goes in chunks of the
+#: largest. Every size compiles once, when the pipeline is built.
+DEPART_LADDER = (64, 256, 1024, 4096)
+
+
+@jax.jit
+def _remove_gathered(state, servers, block):
+    """`placement.remove_batch` of one padded block of gathered
+    departures: (N,) servers (-1 rows are ignored) and the (4, N) stack
+    of cores, p95_eff, is_uf and mem_gb in the state's dtype, split
+    here so the host makes two copies instead of five."""
+    cores, p95_eff, is_uf, mem_gb = block
+    return placement.remove_batch(state, servers, cores, p95_eff, is_uf,
+                                  mem_gb=mem_gb)
+
+
 #: Sentinel distinguishing "kwarg not passed" from an explicit None on
 #: the deprecated constructor kwargs.
 _UNSET = object()
@@ -277,6 +294,10 @@ class ServePipeline:
                 "alarm arithmetic to size its reclaim")
         self.config = replace(config, planes=planes)
         self.table = table
+        # live departure rows not yet applied to the state: (servers,
+        # (4, n) float block) per run, applied in one dispatch before
+        # the next read of the aggregates (`_flush_departures`)
+        self._pending_departures: list[tuple] = []
         self.state = state
         # observability plane (repro.obs, DESIGN.md §14) — purely
         # host-side consumers of outputs the kernels already produce,
@@ -374,6 +395,20 @@ class ServePipeline:
                     f"the pipeline's {self.blades_per_chassis} — power "
                     "samples would read back as the wrong utilization")
             self._adaptive = self._init_adaptive()
+        self._warm_departures()
+
+    @property
+    def state(self) -> placement.DeviceClusterState:
+        """The cluster's aggregates on the device. Reading them first
+        applies the departures gathered since the last read, so every
+        reader sees each departure pushed so far."""
+        if self._pending_departures:
+            self._flush_departures()
+        return self._state
+
+    @state.setter
+    def state(self, value):
+        self._state = value
 
     @property
     def rho_cap(self):
@@ -817,7 +852,11 @@ class ServePipeline:
         merged earlier that are still pending in the current unfilled
         micro-batch window (batching trades exact stream position for
         batch efficiency; the order stays deterministic and the watt
-        budget is never exceeded either way). Advancing this host's
+        budget is never exceeded either way). Unsharded, the released
+        runs are gathered on the host and applied in one dispatch just
+        before the next read of the aggregates — the next micro-batch's
+        placement, the next cap run, `flush` or a `state` read — which
+        keeps this order (`_apply_departures`). Advancing this host's
         clock can release queued micro-batches — any results are
         returned."""
         with self._push():
@@ -874,6 +913,9 @@ class ServePipeline:
             if self._pending_caps:  # trailing caps with no batch to ride
                 with self._span("cap"):
                     self._flush_caps()
+            if self._pending_departures:    # trailing departures
+                with self._span("depart"):
+                    self._flush_departures()
         if not out:
             return None
         return out[0] if len(out) == 1 else _concat_results(out)
@@ -993,6 +1035,9 @@ class ServePipeline:
         mem = jnp.zeros(pad_to, jnp.float32) \
             .at[:b].set(jnp.asarray(batch.memory_gb))
         valid = jnp.arange(pad_to) < b
+        if self._pending_departures:
+            with self._span("depart"):
+                self._flush_departures()
         with self._span("place"):
             servers = self._place(cores, is_uf, p95_eff, valid, mem)
         self.served += b
@@ -1074,12 +1119,15 @@ class ServePipeline:
 
     def depart(self, servers, cores, p95_eff, is_uf,
                mem_gb=None) -> None:
-        """Release departed VMs' aggregates immediately (batched,
-        order-free) — the 1-host special case. `depart_to` is the
-        stream-ordered per-host path, and like `submit` this refuses
-        multi-host pipelines: applying a departure out of merged-
-        stream order would silently break the deterministic order the
-        merge promises."""
+        """Release departed VMs' aggregates (batched, order-free) — the
+        1-host special case. The rows join the gathered departures and
+        are applied before the next read of the aggregates (the next
+        micro-batch's placement, cap run or `state` read), so every
+        later reader sees them. `depart_to` is the stream-ordered
+        per-host path, and like `submit` this refuses multi-host
+        pipelines: applying a departure out of merged-stream order
+        would silently break the deterministic order the merge
+        promises."""
         if self.config.n_ingest_hosts != 1:
             raise ValueError(
                 "depart() is the single-queue (1-host) path; with "
@@ -1090,16 +1138,59 @@ class ServePipeline:
 
     def _apply_departures(self, servers, cores, p95_eff, is_uf,
                           mem_gb=None) -> None:
-        """Apply a departure batch to the cluster state (the merged-
-        stream consumer; `ShardedServePipeline` overrides with the
-        per-shard route + in-scan pool credit). Queued cap windows
-        flush first: they were merged earlier and must read the
-        pre-departure aggregates."""
+        """Take a departure batch (the merged-stream consumer;
+        `ShardedServePipeline` overrides with the per-shard route +
+        in-scan pool credit). Queued cap windows flush first: they were
+        merged earlier and must read the pre-departure aggregates. The
+        batch's live rows (``servers >= 0``; `remove_batch` ignores the
+        rest) are then gathered on the host, and `_flush_departures`
+        applies every gathered run in one dispatch before the next read
+        or write of the aggregates: the next micro-batch's placement,
+        the next cap run, a `flush` or any `state` read. So the order
+        `depart_to` promises is unchanged; only the number of
+        dispatches falls. ``mem_gb=None`` releases zero GB."""
         self._flush_caps()
-        self.state = placement.remove_batch(
-            self.state, jnp.asarray(servers), jnp.asarray(cores),
-            jnp.asarray(p95_eff), jnp.asarray(is_uf),
-            mem_gb=None if mem_gb is None else jnp.asarray(mem_gb))
+        servers = np.asarray(servers)
+        live = servers >= 0
+        if not live.any():
+            return
+        block = np.zeros((4, int(live.sum())),
+                         self._state.free_cores.dtype)
+        for row, col in zip(block, (cores, p95_eff, is_uf, mem_gb)):
+            if col is not None:
+                row[:] = np.asarray(col)[live]
+        self._pending_departures.append(
+            (servers[live].astype(np.int32), block))
+
+    def _flush_departures(self) -> None:
+        """Apply every gathered departure run to the state: one
+        `remove_batch` dispatch (a ``remove`` span) per chunk of at most
+        `DEPART_LADDER`'s largest size, padded with ignored rows to the
+        smallest size that holds it. Rows keep their push order, so on
+        a backend that scatters in row order the result equals one
+        dispatch per run."""
+        parts, self._pending_departures = self._pending_departures, []
+        servers = np.concatenate([p[0] for p in parts])
+        block = np.concatenate([p[1] for p in parts], axis=1)
+        top = DEPART_LADDER[-1]
+        for lo in range(0, len(servers), top):
+            n = min(top, len(servers) - lo)
+            size = next(s for s in DEPART_LADDER if s >= n)
+            srv = np.full(size, -1, np.int32)
+            srv[:n] = servers[lo:lo + n]
+            blk = np.zeros((4, size), block.dtype)
+            blk[:, :n] = block[:, lo:lo + n]
+            with self._span("remove"):
+                self._state = _remove_gathered(self._state, srv, blk)
+
+    def _warm_departures(self) -> None:
+        """Compile the gathered removal at every `DEPART_LADDER` size
+        with an all-ignored batch (its result is dropped), so no
+        departure compiles while serving."""
+        dtype = self._state.free_cores.dtype
+        for size in DEPART_LADDER:
+            _remove_gathered(self._state, np.full(size, -1, np.int32),
+                             np.zeros((4, size), dtype))
 
     # -- power-emergency plane (serve.emergency) ---------------------------
     def _apply_caps(self, batch: CapBatch, t: np.ndarray) -> None:
@@ -1127,6 +1218,11 @@ class ServePipeline:
             raise ValueError(
                 "received CAPPING events but the pipeline was built "
                 "without emergency_cfg or adaptive_cfg")
+        # departures merged before this run: the scans and the queued
+        # windows read the post-departure aggregates (`cap` holds the
+        # dispatch; a `depart` span here would count it twice)
+        if self._pending_departures:
+            self._flush_departures()
         if self._cap_epoch is None:
             self._cap_epoch = float(t[0])
         t = np.asarray(t, np.float64) - self._cap_epoch
@@ -1148,7 +1244,10 @@ class ServePipeline:
     def _flush_caps(self) -> None:
         """Apply queued cap sub-windows through the standalone kernel —
         the path for windows no placement batch will carry (reads of
-        `emergency`/`alarms`, departures, end-of-stream `flush`)."""
+        `emergency`/`alarms`, departures, end-of-stream `flush`). Each
+        window reads `state`, which applies any gathered departures
+        first (none, in merged order: a cap run applies them before it
+        queues a window)."""
         pending, self._pending_caps = self._pending_caps, []
         for chassis, power_w, t in pending:
             with self._span("emergency"):
@@ -1380,6 +1479,10 @@ class ShardedServePipeline(ServePipeline):
         self._ratio_prev = np.ones(config.n_shards)
         self.spill_info = {"rounds": 0, "spilled": 0,
                            "spill_admitted": 0}
+
+    def _warm_departures(self) -> None:
+        """Nothing to compile: departures go straight to the shards
+        (`_apply_departures`), so the gathered buffer stays empty."""
 
     def _query(self, packed, meta, x):
         if self.mesh is None:

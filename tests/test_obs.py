@@ -236,7 +236,7 @@ def test_spans_land_in_the_profiler_trace(tmp_path, obs_world):
     assert {e[0] for e in serve} == {
         "serve." + n for n in ("ingest", "merge", "featurize", "infer",
                                "place", "commit", "fetch", "record",
-                               "depart", "cap", "emergency")}
+                               "depart", "remove", "cap", "emergency")}
     assert all(outer[0][1] <= s and e <= outer[0][2]
                for _, s, e in serve)
 
@@ -434,7 +434,10 @@ def test_fetch_count_follows_batches_and_sweeps(obs_world):
     assert totals["commit"][0] == totals["record"][0] == batches
     assert totals["cap"][0] == PLANE_SWEEPS
     assert totals["emergency"][0] == PLANE_SWEEPS
-    assert totals["depart"][0] == 1
+    # the departure run's push, then the gathered removal in front of
+    # the next batch's placement: one ``remove`` dispatch nested in it
+    assert totals["depart"][0] == 2
+    assert totals["remove"][0] == 1
     # the work spans' total counts each outermost span once
     outer = ("ingest", "merge", "featurize", "infer", "place", "commit",
              "record", "depart", "cap")

@@ -28,6 +28,7 @@ from repro.serve import emergency, placement, sharding
 from repro.serve.inference import (ForestMeta, PackedForest,
                                    PackedService, ServiceMeta,
                                    served_query)
+from repro.serve.pipeline import DEPART_LADDER, _remove_gathered
 from repro.sim import fleet
 from repro.sim.chassis_sim import paper_chassis_specs
 
@@ -148,6 +149,16 @@ def test_place_batch_caps_compiles(one_chip):
         row(jnp.float32), row(jnp.bool_), row(jnp.float32),
         row(jnp.bool_), cap, SchedulerPolicy(), CORES, ecfg,
         mem_gb=row(jnp.float32)).compile()
+    assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("size", DEPART_LADDER)
+def test_gathered_removal_compiles(one_chip, size):
+    state = _specs(_cluster(), one_chip)
+    compiled = _remove_gathered.lower(
+        state, jax.ShapeDtypeStruct((size,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((4, size), jnp.float32,
+                             sharding=one_chip)).compile()
     assert compiled.memory_analysis() is not None
 
 
