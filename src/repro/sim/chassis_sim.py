@@ -20,11 +20,11 @@ from __future__ import annotations
 
 from repro.core.power_model import ServerPowerModel
 from repro.sim.fleet import (FleetResult, ServerSpec, SimResult, VMSpec,
-                             _uf_load_trace, run_fleet)
+                             run_fleet)
 
 __all__ = ["VMSpec", "ServerSpec", "SimResult", "simulate_server",
            "simulate_chassis", "paper_single_server_spec",
-           "paper_chassis_specs", "_uf_load_trace"]
+           "paper_chassis_specs"]
 
 
 def simulate_server(spec: ServerSpec, budget_w: float | None,

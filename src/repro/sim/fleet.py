@@ -56,14 +56,6 @@ class ServerSpec:
     n_cores: int = 40
 
 
-def _uf_load_trace(rng, n_steps: int, base: float) -> np.ndarray:
-    """Fluctuating interactive load (paper Fig. 4 power wiggles)."""
-    wave = 0.12 * np.sin(np.linspace(0, 6 * np.pi, n_steps))
-    slow = 0.06 * np.sin(np.linspace(0, 1.5 * np.pi, n_steps))
-    noise = rng.normal(0, 0.03, n_steps)
-    return np.clip(base + wave + slow + noise, 0.05, 1.2)
-
-
 # --- padded fleet layout --------------------------------------------------
 
 class LayoutArrays(NamedTuple):
@@ -180,15 +172,29 @@ def build_layout(specs: list, pad_uf_to: int = 0, pad_nuf_to: int = 0,
                        _ids(nuf_members, len(nuf_valid)))
 
 
-def build_uf_traces(layout: FleetLayout, n_steps: int, seed: int,
-                    load_scale: float = 1.0) -> np.ndarray:
-    """(n_steps, Vu) offered-load traces, drawn in the seed's VM order."""
-    rng = np.random.default_rng(seed)
-    out = np.zeros((n_steps, len(layout.uf_valid)), _F32)
-    for v in range(len(layout.uf_valid)):
-        if layout.uf_valid[v]:
-            out[:, v] = _uf_load_trace(rng, n_steps, layout.uf_loads[v])
-    return out * _F32(load_scale)
+def build_uf_traces(layout: FleetLayout, n_steps: int,
+                    seed: int | np.ndarray,
+                    load_scale: float | np.ndarray = 1.0) -> np.ndarray:
+    """Fluctuating interactive offered load (paper Fig. 4 power wiggles).
+
+    An int `seed` gives one chassis' (n_steps, Vu) traces; a (B,) seed
+    array gives (B, n_steps, Vu), with `load_scale` a scalar or (B,).
+    Each seed's generator draws its valid UF slots' noise in one
+    (n_valid, n_steps) normal draw whose rows come in VM order, so the
+    draw order per chassis is unchanged: one n_steps draw per VM.
+    Padded slots draw nothing and stay 0."""
+    seeds = np.asarray(seed)
+    wave = 0.12 * np.sin(np.linspace(0, 6 * np.pi, n_steps))
+    slow = 0.06 * np.sin(np.linspace(0, 1.5 * np.pi, n_steps))
+    idx = np.flatnonzero(layout.uf_valid)
+    mean = (layout.uf_loads[idx].astype(np.float64)[:, None] + wave) + slow
+    out = np.zeros((seeds.size, n_steps, len(layout.uf_valid)), _F32)
+    for b, s in enumerate(seeds.reshape(-1)):
+        noise = np.random.default_rng(int(s)).normal(0, 0.03, mean.shape)
+        noise += mean
+        out[b][:, idx] = np.clip(noise, 0.05, 1.2, out=noise).T
+    out *= np.asarray(load_scale, _F32).reshape(-1, 1, 1)
+    return out[0] if seeds.ndim == 0 else out
 
 
 def stack_layouts(layouts: list) -> LayoutArrays:
@@ -425,9 +431,7 @@ def run_fleet(specs: list, budgets_w, mode: str,
             base = build_uf_traces(layout, n_steps, int(seeds[0]))
             traces = base[None] * scales[:, None, None]
         else:
-            traces = np.stack([
-                build_uf_traces(layout, n_steps, int(s), float(sc))
-                for s, sc in zip(seeds, scales)])
+            traces = build_uf_traces(layout, n_steps, seeds, scales)
     traces = np.asarray(traces, _F32)
     if traces.ndim == 2:
         traces = np.broadcast_to(traces[None], (n_runs,) + traces.shape)

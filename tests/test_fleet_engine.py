@@ -2,6 +2,8 @@
 the numpy oracle on the seed's Fig 4-6 configurations (per-step chassis
 power, min NUF frequency, RAPL-engaged fraction), sweeps vmap cleanly,
 and padding rules hold."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -9,8 +11,8 @@ from repro.core.power_model import F_MAX, F_MIN
 from repro.sim.chassis_sim import (paper_chassis_specs,
                                    paper_single_server_spec)
 from repro.sim.fleet import (ServerSpec, VMSpec, build_layout,
-                             fmin_to_pstate, frontier, run_fleet,
-                             run_fleet_layouts, stack_layouts,
+                             build_uf_traces, fmin_to_pstate, frontier,
+                             run_fleet, run_fleet_layouts, stack_layouts,
                              sweep_scenarios)
 
 DUR = 60.0          # 300 steps: long enough to cover cap/lift episodes
@@ -71,7 +73,6 @@ def test_heterogeneous_layouts_parity():
                for sp in chassis]
     la = stack_layouts(layouts)
     n_steps = int(DUR / 0.2)
-    from repro.sim.fleet import build_uf_traces
     traces = np.stack([build_uf_traces(lo, n_steps, seed=9 + i)
                        for i, lo in enumerate(layouts)])
     kw = dict(budgets_w=np.full(2, 620.0), mode="per_vm", traces=traces)
@@ -170,3 +171,91 @@ def test_sweep_scenarios_grid_and_frontier():
     assert (fr["oversubscription"][1] >=
             fr["oversubscription"][0] - 1e-9).all()
     assert fmin_to_pstate(F_MIN) == 10 and fmin_to_pstate(F_MAX) == 0
+
+
+def _per_vm_traces(layout, n_steps, seed, load_scale=1.0):
+    """The oracle: one generator per chassis and one n_steps draw per
+    valid UF VM, in slot order, each VM's trace built on its own."""
+    rng = np.random.default_rng(seed)
+    out = np.zeros((n_steps, len(layout.uf_valid)), np.float32)
+    for v in range(len(layout.uf_valid)):
+        if layout.uf_valid[v]:
+            wave = 0.12 * np.sin(np.linspace(0, 6 * np.pi, n_steps))
+            slow = 0.06 * np.sin(np.linspace(0, 1.5 * np.pi, n_steps))
+            noise = rng.normal(0, 0.03, n_steps)
+            out[:, v] = np.clip(layout.uf_loads[v] + wave + slow + noise,
+                                0.05, 1.2)
+    return out * np.float32(load_scale)
+
+
+def _campus():
+    return build_layout(paper_chassis_specs(balanced=True))
+
+
+def _gapped():
+    """Valid UF slots that are not a prefix of the slot axis."""
+    lo = build_layout([ServerSpec(vms=[VMSpec(4, True, load=0.6),
+                                       VMSpec(4, True, load=0.9),
+                                       VMSpec(4, True, load=0.7),
+                                       VMSpec(8, False)])],
+                      pad_uf_to=5)
+    return dataclasses.replace(
+        lo, uf_valid=np.array([True, False, True, True, False]),
+        uf_loads=np.array([0.6, 0.0, 0.9, 0.7, 0.0], np.float32))
+
+
+_BIG = [0, 7, 2 ** 31 - 2, 2 ** 33 + 5]
+TRACE_CASES = {
+    "campus": (_campus, 150, _BIG, 1.0),
+    "padded_uf_slots": (lambda: build_layout(
+        paper_chassis_specs(balanced=True), pad_uf_to=40), 150, _BIG, 1.0),
+    "gapped_uf_slots": (_gapped, 150, [3, 11, 2 ** 32 + 1], 1.0),
+    "no_uf_vm": (lambda: build_layout(
+        [ServerSpec(vms=[VMSpec(8, False)] * 3)] * 2), 150, [1, 2], 1.0),
+    "load_scale": (_campus, 150, _BIG, 0.8),
+    "load_scale_per_seed": (_campus, 150, _BIG, [0.8, 1.0, 1.3, 0.55]),
+    "short_episode": (_campus, 37, _BIG, 1.0),
+    "long_episode": (lambda: build_layout([paper_single_server_spec()]),
+                     3000, [5, 2 ** 31 - 2], 1.0),
+    "seed_array": (_campus, 150,
+                   list(np.random.default_rng(2).integers(
+                       0, 2 ** 31 - 1, 64)), 1.2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TRACE_CASES))
+def test_batched_traces_match_the_per_vm_loop(case):
+    """One draw per chassis gives, bit for bit, the traces the per-VM
+    loop drew: for a seed array, and for each seed alone."""
+    make, n_steps, seeds, scale = TRACE_CASES[case]
+    layout = make()
+    seeds = np.asarray(seeds, np.int64)
+    scales = np.broadcast_to(np.asarray(scale, np.float32), seeds.shape)
+    want = np.stack([_per_vm_traces(layout, n_steps, int(s), sc)
+                     for s, sc in zip(seeds, scales)])
+    got = build_uf_traces(layout, n_steps, seeds, scale)
+    assert got.dtype == np.float32
+    assert got.shape == (len(seeds), n_steps, len(layout.uf_valid))
+    assert np.array_equal(got, want)
+    one_at_a_time = np.stack([build_uf_traces(layout, n_steps, int(s), sc)
+                              for s, sc in zip(seeds, scales)])
+    assert np.array_equal(got, one_at_a_time)
+    assert not got[:, :, ~layout.uf_valid].any()
+
+
+def test_run_fleet_draws_the_per_vm_traces():
+    """run_fleet's per-seed and shared-seed traces are the oracle's,
+    scaled per run in float32."""
+    specs = [paper_single_server_spec()]
+    layout = build_layout(specs)
+    n_steps, scales = 20, np.array([1.0, 0.8, 1.1], np.float32)
+    for seeds in ([4, 2 ** 31 - 2, 9], [6, 6, 6]):
+        traces = np.stack([_per_vm_traces(layout, n_steps, s) * sc
+                           for s, sc in zip(seeds, scales)])
+        kw = dict(budgets_w=[230.0] * 3, mode="per_vm",
+                  duration_s=n_steps * 0.2, backend="numpy")
+        a = run_fleet(specs, seed=np.asarray(seeds), load_scale=scales,
+                      **kw)
+        b = run_fleet(specs, traces=traces, **kw)
+        np.testing.assert_array_equal(a.power_w, b.power_w)
+        np.testing.assert_array_equal(a.uf_p95_latency, b.uf_p95_latency)
